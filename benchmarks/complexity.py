@@ -5,8 +5,9 @@ so total training work under i_max ~ N scales ~ N².
 This benchmark measures the discrete-event engine itself (``engine='event'``
 so the fused zero-latency shortcut never kicks in) across a sweep of map
 sizes N and across *placements*: the single-pool engine at every N, plus
-mesh-partitioned points (``placement='mesh'``) run in a subprocess with XLA
-host virtual devices. Two claims come out:
+mesh-partitioned points (``placement='mesh'``) run through
+``common.mesh_point``: over the real devices on an accelerator, in a
+subprocess with XLA host virtual devices on the CPU. Two claims come out:
 
 - **algorithmic**: ops/sample (e + greedy steps + cascade size) grows at
   most linearly in N;
@@ -27,8 +28,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -40,26 +39,13 @@ from benchmarks import common
 TIME_GROWTH_BUDGET = 2.0
 OPS_GROWTH_BUDGET = 1.5
 
-_WORKER = r"""
-import json, os, sys
-cfgj = json.loads(sys.argv[1])
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + str(cfgj["shards"]))
-sys.path.insert(0, cfgj["repo"])
-sys.path.insert(0, os.path.join(cfgj["repo"], "src"))
-from benchmarks import complexity
-print(json.dumps(complexity.measure(
-    side=cfgj["side"], events=cfgj["events"], shards=cfgj["shards"])))
-"""
-
-
 def measure(side: int, events: int, shards: int = 1, seed: int = 7) -> dict:
     """Time ``events`` event-engine samples on a ``side``² map.
 
     Compiles on a throwaway call, then times ``repeat`` runs and keeps the
     best (dispatch noise only inflates, never deflates). Returns one
     benchmark row; runs under whatever devices are visible — mesh points
-    call this through a subprocess that forces ``shards`` host devices.
+    call this through ``common.mesh_point``.
     """
     from repro.core import afm as afm_lib
     from repro.core import events as events_lib
@@ -100,24 +86,6 @@ def measure(side: int, events: int, shards: int = 1, seed: int = 7) -> dict:
             "dropped": int(rep.dropped)}
 
 
-def _measure_mesh(side: int, events: int, shards: int) -> dict | None:
-    """Run one mesh point in a subprocess (XLA host devices must be forced
-    before jax imports). Returns None when the worker fails — the sweep
-    then reports single-placement rows only rather than dying."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cfgj = json.dumps({"side": side, "events": events, "shards": shards,
-                       "repo": repo})
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    proc = subprocess.run([sys.executable, "-c", _WORKER, cfgj],
-                          capture_output=True, text=True, timeout=1800,
-                          env=env)
-    if proc.returncode != 0:
-        print(f"  mesh point side={side} shards={shards} failed:\n"
-              f"{proc.stderr[-2000:]}", file=sys.stderr, flush=True)
-        return None
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
 def run(quick: bool = True):
     sides = (6, 8, 10, 12) if quick else (8, 12, 16, 20)
     per_n = 4 if quick else 16                # events = per_n · N per point
@@ -132,11 +100,11 @@ def run(quick: bool = True):
     for side in sides[-2:]:
         if side % 2:
             continue
-        row = _measure_mesh(side, events=per_n * side * side, shards=2)
-        if row is not None:
-            rows.append(row)
-            print(f"  N={row['N']:4d} mesh/s=2  "
-                  f"{row['us_per_sample']:9.1f} us/sample", flush=True)
+        row = common.mesh_point("complexity", side=side,
+                                events=per_n * side * side, shards=2)
+        rows.append(row)
+        print(f"  N={row['N']:4d} mesh/s=2  "
+              f"{row['us_per_sample']:9.1f} us/sample", flush=True)
 
     single = [r for r in rows if r["placement"] == "single"]
     lo, hi = single[0], single[-1]

@@ -13,10 +13,11 @@ accounting. Two structural gates make this CI-assertable:
   exactly — zero unaccounted messages, per shard and globally.
 
 Single-pool rows run in-process; 2-shard mesh rows (same sweep points, plus
-a straggler multiplier) run in a subprocess with XLA host devices forced,
-like ``benchmarks.complexity``. Every row uses ``engine='event'`` so the
-fault-free baseline and the faulty runs time the same discrete-event
-runtime.
+a straggler multiplier) run through ``common.mesh_point``, like
+``benchmarks.complexity``: over the real devices on an accelerator, in a
+subprocess with XLA host devices forced on the CPU. Every row uses
+``engine='event'`` so the fault-free baseline and the faulty runs time the
+same discrete-event runtime.
 
     PYTHONPATH=src python -m benchmarks.fault_bench [--full]
     # CI smoke:
@@ -26,9 +27,6 @@ runtime.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -42,20 +40,6 @@ DEGRADATION_BUDGET = 1.5
 
 P_LOSS_SWEEP = (0.0, 0.05, 0.1, 0.2)
 DROPOUT_SWEEP = (0.1, 0.25)
-
-_WORKER = r"""
-import json, os, sys
-cfgj = json.loads(sys.argv[1])
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + str(cfgj["shards"]))
-sys.path.insert(0, cfgj["repo"])
-sys.path.insert(0, os.path.join(cfgj["repo"], "src"))
-from benchmarks import fault_bench
-print(json.dumps(fault_bench.measure(
-    side=cfgj["side"], events=cfgj["events"], plan=cfgj["plan"],
-    shards=cfgj["shards"])))
-"""
-
 
 def measure(side: int, events: int, plan: dict | None,
             shards: int = 1, seed: int = 7) -> dict:
@@ -110,25 +94,6 @@ def measure(side: int, events: int, plan: dict | None,
     }
 
 
-def _measure_mesh(side: int, events: int, plan: dict | None,
-                  shards: int) -> dict | None:
-    """One mesh point in a subprocess (XLA host devices must be forced
-    before jax imports). None when the worker fails — the sweep then
-    reports single-pool rows only rather than dying."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cfgj = json.dumps({"side": side, "events": events, "shards": shards,
-                       "plan": plan, "repo": repo})
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    proc = subprocess.run([sys.executable, "-c", _WORKER, cfgj],
-                          capture_output=True, text=True, timeout=1800,
-                          env=env)
-    if proc.returncode != 0:
-        print(f"  mesh point shards={shards} plan={plan} failed:\n"
-              f"{proc.stderr[-2000:]}", file=sys.stderr, flush=True)
-        return None
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
 def run(quick: bool = True, with_mesh: bool = True):
     side = 6 if quick else 10
     events = 16 * side * side
@@ -160,9 +125,8 @@ def run(quick: bool = True, with_mesh: bool = True):
                       {"seed": 11, "p_loss": 0.1, "dropout_frac": 0.1,
                        **window, "shard_latency_mult": [1.0, 1.0]}]
         for plan in mesh_plans:
-            row = _measure_mesh(side, events, plan, shards=2)
-            if row is None:
-                continue
+            row = common.mesh_point("fault_bench", side=side, events=events,
+                                    plan=plan, shards=2)
             row["axis"] = "mesh"
             mesh_rows.append(row)
             print(f"  mesh2  plan={plan or 'none'} qe={row['qe']:.4f} "
@@ -196,7 +160,7 @@ if __name__ == "__main__":
                     help="small sweep (the CI smoke variant; also the "
                          "default)")
     ap.add_argument("--no-mesh", action="store_true",
-                    help="skip the 2-shard subprocess points")
+                    help="skip the 2-shard mesh points")
     ap.add_argument("--json-out", default=None, metavar="PATH",
                     help="also write results+derived as JSON "
                          "(BENCH_faults.json, the committed artifact)")

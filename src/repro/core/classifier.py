@@ -24,7 +24,8 @@ def label_units(w: jnp.ndarray, samples: jnp.ndarray, labels: jnp.ndarray,
         # distances (N, chunk)
         w2 = jnp.sum(w * w, axis=-1, keepdims=True)
         s2 = jnp.sum(s * s, axis=-1)
-        q2 = w2 - 2.0 * (w @ s.T) + s2[None, :]
+        cross = jnp.matmul(w, s.T, precision=search_lib.HIGHEST)
+        q2 = w2 - 2.0 * cross + s2[None, :]
         k = jnp.argmin(q2, axis=-1)
         q = jnp.take_along_axis(q2, k[:, None], axis=-1)[:, 0]
         better = q < best_q

@@ -117,12 +117,17 @@ def heuristic_search(w, near, far, samples, key, e: int,
 #: larger maps stream (B, 4096) blocks with a running argmin.
 DEFAULT_UNIT_CHUNK = 4096
 
+#: The exact search's distance matmuls run at full f32. On TPU the default
+#: precision rounds f32 operands to bf16; on CPU the flag changes nothing.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _bmu_block(w_rows, samples, base):
     """Best unit within one block of ``w`` rows; indices offset by ``base``."""
     s2 = jnp.sum(samples * samples, axis=-1)                # (B,)
     w2 = jnp.sum(w_rows * w_rows, axis=-1)                  # (n_block,)
-    q2 = s2[:, None] - 2.0 * (samples @ w_rows.T) + w2[None, :]
+    cross = jnp.matmul(samples, w_rows.T, precision=HIGHEST)
+    q2 = s2[:, None] - 2.0 * cross + w2[None, :]
     idx = jnp.argmin(q2, axis=-1)
     best = jnp.take_along_axis(q2, idx[:, None], axis=-1)[:, 0]
     return (base + idx).astype(jnp.int32), best
@@ -166,6 +171,7 @@ def second_bmu(w, samples):
     """Indices of best and second-best matching units (for topological error)."""
     s2 = jnp.sum(samples * samples, axis=-1)
     w2 = jnp.sum(w * w, axis=-1)
-    q2 = s2[:, None] - 2.0 * (samples @ w.T) + w2[None, :]
+    cross = jnp.matmul(samples, w.T, precision=HIGHEST)
+    q2 = s2[:, None] - 2.0 * cross + w2[None, :]
     top2 = jax.lax.top_k(-q2, 2)[1]
     return top2[:, 0].astype(jnp.int32), top2[:, 1].astype(jnp.int32)

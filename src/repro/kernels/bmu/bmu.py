@@ -8,7 +8,12 @@ Tiling: grid = (B // bb, N // bn); the unit axis is the minor (sequential)
 grid dimension, so each sample tile keeps a running (min, argmin) accumulator
 in its output block while streaming unit tiles through VMEM — one HBM pass
 over W per sample tile, MXU-aligned block shapes (multiples of 128 on the
-contracting/lane dims).
+contracting/lane dims). Every block is 2-D, as TPU tiling requires: ``|w|^2``
+rides lane-major as a (1, bn) row and the running (min, argmin) are (bb, 1)
+columns.
+
+The exact tier runs the cross term at ``Precision.HIGHEST`` (full f32 on the
+MXU; the default would round the operands to bf16 on TPU).
 
 |s|^2 is dropped inside the kernel (constant in j — argmin-invariant) and
 added back by the wrapper, which also polishes the returned distance with one
@@ -41,10 +46,13 @@ def _bmu_kernel(w_ref, s_ref, w2_ref, min_ref, idx_ref, *, block_n: int,
         w = w.astype(jnp.bfloat16)
     cross = jax.lax.dot_general(
         s, w, (((1,), (1,)), ((), ())),
+        precision=(None if precision == "bf16"
+                   else jax.lax.Precision.HIGHEST),
         preferred_element_type=jnp.float32)          # (bb, bn)
-    q = w2_ref[...][None, :] - 2.0 * cross           # |w|^2 - 2 w.s
-    local_min = jnp.min(q, axis=1)                   # (bb,)
-    local_arg = jnp.argmin(q, axis=1).astype(jnp.int32) + j * block_n
+    q = w2_ref[...] - 2.0 * cross                    # |w|^2 - 2 w.s
+    local_min = jnp.min(q, axis=1, keepdims=True)    # (bb, 1)
+    local_arg = (jnp.argmin(q, axis=1, keepdims=True).astype(jnp.int32)
+                 + j * block_n)
     better = local_min < min_ref[...]
     idx_ref[...] = jnp.where(better, local_arg, idx_ref[...])
     min_ref[...] = jnp.where(better, local_min, min_ref[...])
@@ -64,7 +72,7 @@ def bmu_pallas(w: jnp.ndarray, s: jnp.ndarray, *, block_b: int = 128,
     n, d = w.shape
     b, _ = s.shape
     assert n % block_n == 0 and b % block_b == 0, (n, b)
-    w2 = jnp.sum(w.astype(jnp.float32) ** 2, axis=-1)
+    w2 = jnp.sum(w.astype(jnp.float32) ** 2, axis=-1)[None, :]
     grid = (b // block_b, n // block_n)
     min_out, idx_out = pl.pallas_call(
         functools.partial(_bmu_kernel, block_n=block_n, precision=precision),
@@ -72,17 +80,17 @@ def bmu_pallas(w: jnp.ndarray, s: jnp.ndarray, *, block_b: int = 128,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),   # w tile
             pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),   # s tile
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),       # |w|^2 tile
+            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),   # |w|^2 tile
         ],
         out_specs=[
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),       # running min
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),       # running argmin
+            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),   # running min
+            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),   # running argmin
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.float32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         interpret=interpret,
     )(w, s, w2)
     s2 = jnp.sum(s.astype(jnp.float32) ** 2, axis=-1)
-    return idx_out, jnp.maximum(min_out + s2, 0.0)
+    return idx_out[:, 0], jnp.maximum(min_out[:, 0] + s2, 0.0)

@@ -17,7 +17,8 @@ def bmu_ref(w: jnp.ndarray, s: jnp.ndarray):
     s = s.astype(jnp.float32)
     w2 = jnp.sum(w * w, axis=-1)
     s2 = jnp.sum(s * s, axis=-1)
-    q2 = s2[:, None] - 2.0 * (s @ w.T) + w2[None, :]
+    cross = jnp.matmul(s, w.T, precision=jax.lax.Precision.HIGHEST)
+    q2 = s2[:, None] - 2.0 * cross + w2[None, :]
     idx = jnp.argmin(q2, axis=-1).astype(jnp.int32)
     best = jnp.take_along_axis(q2, idx[:, None], axis=-1)[:, 0]
     return idx, jnp.maximum(best, 0.0)

@@ -26,12 +26,30 @@ Two distance tiers for the in-kernel search (``precision``):
   traffic for W in the distance pass, tolerance-tested (index agreement +
   q2 ULP bound) rather than bitwise. See ``kernels.bmu.ref.bmu_bf16_ref``.
 
+The exact tier's distance matmul runs at ``Precision.HIGHEST``: on TPU the
+default rounds f32 operands to bf16, which is the bf16 tier, not the exact
+one. On CPU the flag changes nothing.
+
 Lattice shifts use rolls + 2-D iota masks (the ``kernels.cascade`` idiom —
 TPU-friendly) summed in ``core.cascade._shift_sum``'s exact order, so the
-float weight updates stay bitwise against the concatenate-based oracle. The
-Eq. (3) merge keeps the oracle's scatter-adds (``.at[gmu].add``); on a real
-TPU Mosaic may prefer a one-hot matmul, which would need its own parity
-audit — the interpret path (CI) is the contract here.
+float weight updates stay bitwise against the concatenate-based oracle.
+
+Mosaic lowers neither gathers nor scatter-adds, so the kernel has none:
+
+- the searched distance is the row minimum (the value at the argmin);
+- the bf16 tier's polish gathers the winners' rows with a one-hot matmul at
+  ``HIGHEST`` (an exact row copy);
+- the Eq. (3) merge walks the batch in order and adds sample ``k`` to the
+  rows whose unit equals ``gmu[k]`` with a compare-and-select. That is the
+  scatter-add's own summation order, so the merge's sums and counts stay
+  bitwise against ``afm.adapt_merge`` even when several samples share a
+  unit (DESIGN.md §11 has the one CPU caveat, on the mean).
+
+The whole map lives in VMEM (``grid=()``), so the map size is capped:
+``VMEM_LIMIT_BYTES`` is the scoped-VMEM budget the kernel asks Mosaic for,
+``vmem_bytes`` what a map needs (``ops.fused_step_parts`` refuses a map
+that needs more, with an error). At the paper's dim 784 the largest side
+that fits is 56.
 """
 from __future__ import annotations
 
@@ -40,6 +58,27 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: Scoped-VMEM budget the kernel asks Mosaic for (v5e has 128 MiB of VMEM).
+VMEM_LIMIT_BYTES = 100 * 2**20
+#: Whole-map f32 copies the kernel keeps in VMEM at once: input, output, the
+#: merge's target sum, the wave loop's carries and temporaries. Fitted to
+#: what Mosaic allocates on v5e at dim 784: side 56 compiles within
+#: ``VMEM_LIMIT_BYTES``, side 64 asks for 111 MiB.
+W_COPIES = 8
+
+
+def _tiled_bytes(rows: int, cols: int) -> int:
+    """Bytes of a 32-bit (rows, cols) array padded to (8, 128) tiles."""
+    return (-(-rows // 8) * 8) * (-(-cols // 128) * 128) * 4
+
+
+def vmem_bytes(side: int, dim: int, wave_cap: int) -> int:
+    """Estimated VMEM the kernel needs for a side x side map at ``dim``:
+    ``W_COPIES`` weight matrices plus the wave draws and lattice arrays."""
+    return (W_COPIES * _tiled_bytes(side * side, dim)
+            + (4 * wave_cap + 16) * _tiled_bytes(side, side))
 
 
 def _masks(side: int):
@@ -48,15 +87,18 @@ def _masks(side: int):
     return row, col
 
 
-def _shift_sum3(x3, row, col):
+def _shift_sum3(x3):
     """4-neighbour sum for (side, side, D), zero beyond the boundary —
     value-identical to ``cascade._shift_sum`` (same shifted arrays, same
-    ``((up + dn) + lf) + rt`` addition order)."""
+    ``((up + dn) + lf) + rt`` addition order). The masks are built as
+    (side, side, 1) iotas: Mosaic cannot add a unit lane dim to a bool."""
     side = x3.shape[0]
-    up = jnp.where((row < side - 1)[..., None], jnp.roll(x3, -1, axis=0), 0.0)
-    dn = jnp.where((row > 0)[..., None], jnp.roll(x3, 1, axis=0), 0.0)
-    lf = jnp.where((col < side - 1)[..., None], jnp.roll(x3, -1, axis=1), 0.0)
-    rt = jnp.where((col > 0)[..., None], jnp.roll(x3, 1, axis=1), 0.0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (side, side, 1), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (side, side, 1), 1)
+    up = jnp.where(row < side - 1, jnp.roll(x3, -1, axis=0), 0.0)
+    dn = jnp.where(row > 0, jnp.roll(x3, 1, axis=0), 0.0)
+    lf = jnp.where(col < side - 1, jnp.roll(x3, -1, axis=1), 0.0)
+    rt = jnp.where(col > 0, jnp.roll(x3, 1, axis=1), 0.0)
     return up + dn + lf + rt
 
 
@@ -80,7 +122,7 @@ def _fused_kernel(*refs, b: int, side: int, d: int, theta: int, budget: int,
     else:
         (w_ref, c_ref, s_ref, scal_ref, drive_ref, bern_ref,
          w_out, c_out, fired_out, stats_out, recv_out,
-         gmu_out, q2_out) = refs
+         gmu_ref, q2_out) = refs
     n = side * side
     w = w_ref[...]                                   # (N, D) — the HBM read
     s = s_ref[...]                                   # (B, D)
@@ -88,40 +130,54 @@ def _fused_kernel(*refs, b: int, side: int, d: int, theta: int, budget: int,
     l_c = scal_ref[1]
     row, col = _masks(side)
 
-    # ---- search (Eq. 1) — skipped when the relay race ran outside
-    if has_search:
-        gmu = gmu_ref[...]
-    elif precision == "exact":
-        # op-for-op ``search.exact_bmu``'s single-block path (bitwise)
+    # ---- search (Eq. 1) — skipped when the relay race ran outside; the
+    # winners land in ``gmu_ref`` ((B, 1) i32) either way
+    if not has_search:
         s2 = jnp.sum(s * s, axis=-1)
         w2 = jnp.sum(w * w, axis=-1)
-        q2m = s2[:, None] - 2.0 * (s @ w.T) + w2[None, :]
-        idx = jnp.argmin(q2m, axis=-1)
-        best = jnp.take_along_axis(q2m, idx[:, None], axis=-1)[:, 0]
-        gmu = idx.astype(jnp.int32)
-        gmu_out[...] = gmu
-        q2_out[...] = jnp.maximum(best, 0.0)
-    else:
-        # bf16 tier: cross term on bf16 inputs, f32 accumulate, then an
-        # exact-f32 polish of the winner (``kernels.bmu.ref.bmu_bf16_ref``)
-        s2 = jnp.sum(s * s, axis=-1)
-        w2 = jnp.sum(w * w, axis=-1)
-        cross = jax.lax.dot_general(
-            s.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        q2a = s2[:, None] - 2.0 * cross + w2[None, :]
-        gmu = jnp.argmin(q2a, axis=-1).astype(jnp.int32)
-        dw = w[gmu] - s
-        gmu_out[...] = gmu
-        q2_out[...] = jnp.maximum(jnp.sum(dw * dw, axis=-1), 0.0)
+        if precision == "exact":
+            # op-for-op ``search.exact_bmu``'s single-block path (bitwise)
+            q2m = s2[:, None] - 2.0 * jax.lax.dot_general(
+                s, w, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST) + w2[None, :]
+            gmu_ref[...] = jnp.argmin(q2m, axis=-1,
+                                      keepdims=True).astype(jnp.int32)
+            q2_out[...] = jnp.maximum(jnp.min(q2m, axis=-1, keepdims=True),
+                                      0.0)
+        else:
+            # bf16 tier: cross term on bf16 inputs, f32 accumulate, then an
+            # exact-f32 polish of the winner (``kernels.bmu.ref.bmu_bf16_ref``)
+            cross = jax.lax.dot_general(
+                s.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            q2a = s2[:, None] - 2.0 * cross + w2[None, :]
+            gmu = jnp.argmin(q2a, axis=-1, keepdims=True).astype(jnp.int32)
+            onehot = (jax.lax.broadcasted_iota(jnp.int32, (b, n), 1)
+                      == gmu).astype(jnp.float32)
+            dw = jax.lax.dot_general(
+                onehot, w, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST) - s   # w[gmu] - s
+            gmu_ref[...] = gmu
+            q2_out[...] = jnp.maximum(jnp.sum(dw * dw, axis=-1,
+                                              keepdims=True), 0.0)
 
-    # ---- Eq. (3) GMU merge — op-for-op ``afm.adapt_merge``
-    ones = jnp.ones((b,), jnp.float32)
-    counts = jnp.zeros((n,), jnp.float32).at[gmu].add(ones)
-    target_sum = jnp.zeros((n, d), jnp.float32).at[gmu].add(s)
+    # ---- Eq. (3) GMU merge — ``afm.adapt_merge``'s scatter-adds as an
+    # in-order compare-and-select walk over the batch (same summation order)
+    unit = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+
+    def merge_one(k, acc):
+        target_sum, counts = acc
+        hit_k = unit == gmu_ref[pl.ds(k, 1), :]      # (N, 1)
+        return (jnp.where(hit_k, target_sum + s_ref[pl.ds(k, 1), :],
+                          target_sum),
+                jnp.where(hit_k, counts + 1.0, counts))
+
+    target_sum, counts = jax.lax.fori_loop(
+        0, b, merge_one, (jnp.zeros((n, d), jnp.float32),
+                          jnp.zeros((n, 1), jnp.float32)))
     hit = counts > 0
-    mean = target_sum / jnp.maximum(counts, 1.0)[:, None]
-    mean_target = jnp.where(hit[:, None], mean, w)
+    mean = target_sum / jnp.maximum(counts, 1.0)
+    mean_target = jnp.where(hit, mean, w)
     w = w + l_s * (mean_target - w)
 
     # ---- counter drive (precomputed draws)
@@ -130,33 +186,32 @@ def _fused_kernel(*refs, b: int, side: int, d: int, theta: int, budget: int,
     inc = jnp.sum(drive_ref[...] * (k8 < jnp.minimum(gmu_mask, 8)).astype(
         jnp.int32), axis=0)
     c = c_ref[...] + inc
-    fired = c >= theta
+    fired = (c >= theta).astype(jnp.int32)           # i32: Mosaic selects no i1
     w3 = w.reshape(side, side, d)
-    bern_all = bern_ref[...]                         # (w_cap, 4, side, side)
 
     # ---- block-unrolled wave loop: while over blocks of ``unroll``
     # straight-line waves; inactive waves are full-array selects (never
     # arithmetic no-ops — ``w + l_c*0`` would flip -0.0 to +0.0)
     def wave_once(w3, c, fired, widx):
         firedf = fired.astype(jnp.float32)
-        sum_wk = _shift_sum3(w3 * firedf[..., None], row, col)
-        bern = jax.lax.dynamic_index_in_dim(bern_all, widx, keepdims=False)
-        cr = jnp.where(fired, 0, c)
-        recv4 = _shift4_i32(fired.astype(jnp.int32), row, col)
+        sum_wk = _shift_sum3(w3 * firedf[..., None])
+        bern = bern_ref[widx]                        # (4, side, side)
+        cr = jnp.where(fired > 0, 0, c)
+        recv4 = _shift4_i32(fired, row, col)
         n_recv = recv4.sum(axis=0)
         cn = cr + jnp.sum(bern * recv4, axis=0)
-        new_fired = (cn >= theta) & (n_recv > 0)
+        new_fired = ((cn >= theta) & (n_recv > 0)).astype(jnp.int32)
         nf = n_recv.astype(jnp.float32)
         w3n = w3 + l_c * (sum_wk - nf[..., None] * w3)
         return w3n, cn, new_fired, n_recv
 
     def bcond(cc):
-        return jnp.any(cc[2]) & (cc[4] < budget)
+        return jnp.any(cc[2] > 0) & (cc[4] < budget)
 
     def bbody(cc):
         w3, c, fired, size, waves, recv = cc
         for _ in range(unroll):
-            active = jnp.any(fired) & (waves < budget)
+            active = jnp.any(fired > 0) & (waves < budget)
             widx = jnp.minimum(waves, w_cap - 1)     # clamp inactive lanes
             w3n, cn, fn, n_recv = wave_once(w3, c, fired, widx)
             size = size + jnp.where(active, fired.sum(dtype=jnp.int32), 0)
@@ -174,8 +229,9 @@ def _fused_kernel(*refs, b: int, side: int, d: int, theta: int, budget: int,
 
     w_out[...] = w3.reshape(n, d)                    # the one HBM write
     c_out[...] = c
-    fired_out[...] = fired.astype(jnp.int32)
-    stats_out[...] = jnp.stack([size, waves])
+    fired_out[...] = fired
+    stats_out[0] = size
+    stats_out[1] = waves
     recv_out[...] = recv
 
 
@@ -200,15 +256,16 @@ def fused_step_pallas(w, c2, s, scal, drive, bern, gmu=None, *, theta: int,
     w_cap = bern.shape[0]
     has_search = gmu is not None
     full = lambda shape: pl.BlockSpec(shape, lambda: (0,) * len(shape))  # noqa: E731
-    in_specs = [full(w.shape), full(c2.shape), full(s.shape), full((2,)),
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [full(w.shape), full(c2.shape), full(s.shape), smem,
                 full(drive.shape), full(bern.shape)]
     args = [w, c2.astype(jnp.int32), s, scal,
             drive.astype(jnp.int32), bern.astype(jnp.int32)]
     if has_search:  # lint: tracer-ok(static arg-presence flag, not a tracer)
-        in_specs.append(full((b,)))
-        args.append(gmu.astype(jnp.int32))
+        in_specs.append(full((b, 1)))
+        args.append(gmu.astype(jnp.int32).reshape(b, 1))
     out_specs = [full((n, d)), full((side, side)), full((side, side)),
-                 full((2,)), full((side, side))]
+                 smem, full((side, side))]
     out_shape = [
         jax.ShapeDtypeStruct((n, d), jnp.float32),
         jax.ShapeDtypeStruct((side, side), jnp.int32),
@@ -217,10 +274,10 @@ def fused_step_pallas(w, c2, s, scal, drive, bern, gmu=None, *, theta: int,
         jax.ShapeDtypeStruct((side, side), jnp.int32),
     ]
     if not has_search:  # lint: tracer-ok(static arg-presence flag)
-        out_specs += [full((b,)), full((b,))]
-        out_shape += [jax.ShapeDtypeStruct((b,), jnp.int32),
-                      jax.ShapeDtypeStruct((b,), jnp.float32)]
-    return pl.pallas_call(
+        out_specs += [full((b, 1)), full((b, 1))]
+        out_shape += [jax.ShapeDtypeStruct((b, 1), jnp.int32),
+                      jax.ShapeDtypeStruct((b, 1), jnp.float32)]
+    out = pl.pallas_call(
         functools.partial(
             _fused_kernel, b=b, side=side, d=d, theta=int(theta),
             budget=int(budget), w_cap=int(w_cap), unroll=int(unroll),
@@ -229,5 +286,10 @@ def fused_step_pallas(w, c2, s, scal, drive, bern, gmu=None, *, theta: int,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*args)
+    if has_search:  # lint: tracer-ok(static arg-presence flag)
+        return out
+    return (*out[:5], out[5][:, 0], out[6][:, 0])

@@ -32,7 +32,7 @@ from repro.core import search as search_lib
 from repro.kernels.bmu import ops as bmu_ops
 from repro.kernels.bmu import ref as bmu_ref
 from repro.kernels.fused import ref
-from repro.kernels.fused.fused import fused_step_pallas
+from repro.kernels.fused import fused as fused_lib
 
 PRECISIONS = ("exact", "bf16")
 #: Default in-kernel wave budget. The quick-config cascade-stats tables cap
@@ -116,6 +116,14 @@ def fused_step_parts(w, c, samples, k_cascade, cfg, *, l_c, p_i,
         return FusedStep(core.w, core.c, gmu, q2, greedy,
                          core.size, core.waves, core.recv)
 
+    need = fused_lib.vmem_bytes(side, d, wave_cap)
+    if need > fused_lib.VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"kernel='fused' keeps the whole map in VMEM: side {side} at dim "
+            f"{d} needs about {need / 2**20:.0f} MiB, over the kernel's "
+            f"{fused_lib.VMEM_LIMIT_BYTES // 2**20} MiB limit (side 56 is the "
+            f"largest that fits at dim 784); use kernel='staged'")
+
     # ---- kernel path: precompute the PRNG, run the megakernel, finish any
     # over-budget cascade with the oracle's tail loop from chain position
     # ``wave_cap`` (the kernel consumed draws 0..wave_cap-1)
@@ -134,7 +142,7 @@ def fused_step_parts(w, c, samples, k_cascade, cfg, *, l_c, p_i,
 
     scal = jnp.stack([jnp.float32(cfg.l_s), jnp.asarray(l_c, jnp.float32)])
     budget = min(wave_cap, max_waves)
-    out = fused_step_pallas(
+    out = fused_lib.fused_step_pallas(
         w, c.reshape(side, side), samples, scal, draws, bern, gmu,
         theta=theta, budget=budget, unroll=unroll, precision=precision,
         interpret=interpret)

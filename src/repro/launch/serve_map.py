@@ -46,6 +46,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.serving.fleet import MapFleet
 from repro.serving.gateway import MapGateway
 from repro.serving.maps import DEFAULT_BUCKETS, MapService
@@ -237,6 +238,7 @@ def main():
                          "BMU distances, one row per request sample)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
     if args.store and not args.map:
         raise SystemExit("--store needs --map 'name[@version]'")
     if args.artifact and args.map:

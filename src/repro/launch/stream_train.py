@@ -47,6 +47,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.api import AFMConfig, MapStore, TopoMap
 from repro.api.backends import add_backend_argument
 from repro.api.persistence import _state_like
@@ -348,6 +349,7 @@ def main():
     ap.add_argument("--coalesce-ms", type=float, default=1.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     spec = DATASETS[args.dataset]
     xtr, _, xte, _ = make_dataset(args.dataset,

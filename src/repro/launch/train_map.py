@@ -38,6 +38,7 @@ import time
 
 import jax
 
+from repro import compile_cache
 from repro.api import AFMConfig, TopoMap, precision_recall
 from repro.api.backends import add_backend_argument
 from repro.data import DATASETS, make_dataset
@@ -121,6 +122,7 @@ def main():
     ap.add_argument("--name", default=None,
                     help="store key name (default: DATASET-SIDExSIDE)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     spec = DATASETS[args.dataset]
     xtr, ytr, xte, yte = make_dataset(

@@ -1,38 +1,22 @@
-"""Version-tolerant wrappers for the jax sharding surface.
-
-The shard_map / mesh APIs moved between jax releases (``jax.experimental.
-shard_map.shard_map(check_rep=...)`` -> ``jax.shard_map(check_vma=...)``;
-``AbstractMesh(shape_tuple)`` -> ``AbstractMesh(axis_sizes, axis_names)``).
-Everything in repro that touches a mesh goes through these helpers so the
-same code runs on both sides of the move.
-"""
+"""The mesh helpers every mesh user in repro goes through, so the
+replication-check default and the mesh axis types live in one place."""
 from __future__ import annotations
 
 import jax
-from jax.sharding import AbstractMesh
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
     """SPMD-map ``f`` over ``mesh`` with replication checking disabled by
     default (the AFM step mixes replicated and sharded state on purpose)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
-
-
-def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """AbstractMesh((16, 16), ("data", "model")) on any supported jax."""
-    try:
-        return AbstractMesh(axis_sizes, axis_names)
-    except TypeError:
-        # older signature: one tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """Device mesh over the available devices (no axis_types argument —
-    it does not exist pre-0.5 and defaults are fine everywhere)."""
-    return jax.make_mesh(axis_sizes, axis_names)
+    """Device mesh over the available devices, with ``Auto`` axes: arrays
+    placed on it stay usable by plain jnp code outside ``shard_map``
+    (``jax.make_mesh`` defaults to ``Explicit`` axes, under which e.g. a
+    BMU gather on mesh-sharded weights is a sharding type error)."""
+    return jax.make_mesh(axis_sizes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_sizes))

@@ -4,7 +4,10 @@ The goldens pin the engine's *round semantics* bitwise: they were generated
 from the PR-4 dense engine (pre sparse-round optimization, PR 5) and every
 subsequent engine rewrite must reproduce them exactly — weights, counters,
 per-sample aux, and the full ``EventReport`` — across all three latency
-models. Regenerate ONLY when the round semantics change on purpose:
+models. They were recorded with the non-partitionable threefry stream
+(``jax_threefry_partitionable=False``), so ``main`` and the golden test both
+run the cases under ``jax.threefry_partitionable(False)``. Regenerate ONLY
+when the round semantics change on purpose:
 
     PYTHONPATH=src python tests/golden/regen_async_golden.py
 
@@ -65,6 +68,27 @@ CASES = [
 FUSED_CASES = ["small_zero", "ten_zero", "hot_zero"]
 
 
+#: ``q2`` (the reported distance) is the one field allowed to move: XLA:CPU's
+#: distance reduction rounds it differently across builds (1-2 ULP
+#: measured), so it is held to this bound. Every other field is bitwise.
+Q2_ULP_BOUND = 4
+
+
+def assert_matches_golden(out: dict, gold, case: str, label: str = ""):
+    """Compare one run's fields with the recorded fingerprints."""
+    for k, v in out.items():
+        want = gold[f"{case}/{k}"]
+        if k == "q2":
+            ulp = np.abs(np.asarray(v, np.float32).view(np.int32)
+                         .astype(np.int64)
+                         - want.view(np.int32).astype(np.int64))
+            assert ulp.max() <= Q2_ULP_BOUND, (
+                f"{case}/q2 {label}: {ulp.max()} ULP")
+            continue
+        np.testing.assert_array_equal(np.asarray(v), want,
+                                      err_msg=f"{case}/{k} {label}")
+
+
 def run_case(cfg: AFMConfig, num_events: int, ekw: dict, hot: bool):
     """One seeded engine run; seeds are derived from the config so cases
     stay independent."""
@@ -95,7 +119,8 @@ def run_case(cfg: AFMConfig, num_events: int, ekw: dict, hot: bool):
 def main():
     payload = {}
     for name, cfg, num_events, ekw, hot in CASES:
-        out = run_case(cfg, num_events, ekw, hot)
+        with jax.threefry_partitionable(False):
+            out = run_case(cfg, num_events, ekw, hot)
         for k, v in out.items():
             payload[f"{name}/{k}"] = v
         print(f"{name}: rounds={out['rounds']}, deliveries="

@@ -240,6 +240,85 @@ def test_fused_heuristic_search_stays_external_and_bitwise():
         assert_bits_equal(getattr(s1, f), getattr(s2, f), f)
 
 
+def _shared_unit_step(search, groups, p_i):
+    """One fused step, interpreted kernel and oracle, on a batch whose
+    samples crowd onto shared units: ``groups`` samples per crowd."""
+    from repro.core import search as search_lib
+
+    b = sum(groups)
+    cfg = _hot_cfg(4, 24, b, 2)
+    kw, kc = jax.random.split(jax.random.PRNGKey(5))
+    w = jax.random.normal(kw, (cfg.n_units, cfg.dim), jnp.float32)
+    # each crowd sits on its own unit's weights (units 1, 4, 7, ...)
+    unit = 3 * np.repeat(np.arange(len(groups)), groups) + 1
+    noise = 1e-3 * jax.random.normal(kc, (b, cfg.dim), jnp.float32)
+    samples = w[unit] + noise
+    c = jnp.zeros((cfg.n_units,), jnp.int32)
+    res = None
+    if search == "external":
+        zeros = jnp.zeros((b,), jnp.int32)
+        res = search_lib.SearchResult(jnp.asarray(unit, jnp.int32),
+                                      jnp.zeros((b,)), zeros, zeros)
+    precision = "bf16" if search == "bf16" else "exact"
+
+    def step(use_pallas):
+        return jax.jit(lambda w, c, s, k: fused_ops.fused_step_parts(
+            w, c, s, k, cfg, l_c=0.3, p_i=p_i, search_result=res,
+            precision=precision, use_pallas=use_pallas,
+            interpret=use_pallas))(w, c, samples, jax.random.PRNGKey(9))
+
+    got, want = step(True), step(False)
+    counts = np.bincount(np.asarray(want.gmu), minlength=cfg.n_units)
+    assert sorted(counts[counts > 0].tolist()) == sorted(groups), counts
+    return got, want, counts
+
+
+@pytest.mark.parametrize("search", ["external", "exact", "bf16"])
+def test_fused_merge_bitwise_when_samples_share_a_unit(search):
+    """The kernel's one-hot merge walks the batch in order, so it keeps the
+    scatter-add's summation order: the whole step stays bitwise against
+    the oracle when four samples land on one unit (where a reordered sum
+    of four terms would round differently), cascade included."""
+    got, want, _ = _shared_unit_step(search, (4, 2, 1), p_i=0.9)
+    assert int(want.waves) > 0
+    for f in got._fields:
+        assert_bits_equal(getattr(got, f), getattr(want, f), f)
+
+
+def test_fused_merge_mean_within_an_ulp_at_odd_counts():
+    """A unit hit by 3 (5, 6, 7, ...) samples divides its summed target by
+    a count that is not a power of two. XLA:CPU turns that division by a
+    broadcast count into a multiply by its reciprocal in some programs and
+    not in others, so the interpreted kernel and the oracle may round that
+    unit's mean one ULP apart. Every other value stays bitwise."""
+    got, want, counts = _shared_unit_step("external", (3, 2, 1), p_i=0.0)
+    odd = counts == 3
+    ulp = _q2_ulp(np.asarray(got.w)[odd], np.asarray(want.w)[odd])
+    assert ulp.max() <= 2, ulp.max()
+    assert_bits_equal(np.asarray(got.w)[~odd], np.asarray(want.w)[~odd])
+    for f in ("c", "gmu", "size", "waves", "recv"):
+        assert_bits_equal(getattr(got, f), getattr(want, f), f)
+
+
+def test_fused_kernel_refuses_a_map_over_its_vmem_budget():
+    """Above the VMEM ceiling the fused kernel raises, naming the limit —
+    it never quietly falls back to another path."""
+    from repro.kernels.fused import fused as fused_lib
+
+    cfg = afm.AFMConfig(side=64, dim=784, batch=16)
+    assert (fused_lib.vmem_bytes(56, 784, fused_ops.DEFAULT_WAVE_CAP)
+            <= fused_lib.VMEM_LIMIT_BYTES
+            < fused_lib.vmem_bytes(64, 784, fused_ops.DEFAULT_WAVE_CAP))
+    args = (jax.ShapeDtypeStruct((cfg.n_units, cfg.dim), jnp.float32),
+            jax.ShapeDtypeStruct((cfg.n_units,), jnp.int32),
+            jax.ShapeDtypeStruct((cfg.batch, cfg.dim), jnp.float32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    with pytest.raises(ValueError, match="100 MiB limit"):
+        jax.eval_shape(lambda w, c, s, k: fused_ops.fused_step_parts(
+            w, c, s, k, cfg, l_c=0.5, p_i=0.5, use_pallas=True,
+            interpret=False), *args)
+
+
 def test_fused_stage_validates_options():
     with pytest.raises(ValueError, match="search"):
         fused_ops.make_fused_stage(search="nope")

@@ -83,12 +83,12 @@ _GOLDEN_CASES = [("small_zero", {}), ("ten_const", {}), ("ten_exp", {}),
                               for c, e in _GOLDEN_CASES])
 def test_single_placement_matches_golden_bitwise(case, ekw):
     """The explicit ``placement='single'`` spelling must land on the exact
-    golden fingerprints: the seam is a refactor, not a new engine."""
+    golden fingerprints (``q2`` within the regen script's ULP bound): the
+    seam is a refactor, not a new engine."""
     gold = np.load(_GOLDEN_NPZ)
-    out = _flatten(*_run_case(case, ekw=ekw, placement="single"))
-    for k, v in out.items():
-        np.testing.assert_array_equal(np.asarray(v), gold[f"{case}/{k}"],
-                                      err_msg=f"{case}/{k}")
+    with jax.threefry_partitionable(False):   # the goldens' stream
+        out = _flatten(*_run_case(case, ekw=ekw, placement="single"))
+    _REGEN.assert_matches_golden(out, gold, case)
 
 
 @pytest.mark.parametrize("case", ["small_zero", "ten_exp"])

@@ -2,17 +2,18 @@
 touching device state (AbstractMesh)."""
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro import configs
 from repro.models import transformer
-from repro.sharding import compat, rules
+from repro.sharding import rules
 
 
 def _mesh(multi_pod=False):
     if multi_pod:
-        return compat.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return compat.abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _check_divisible(tree_abs, tree_specs, mesh):

@@ -1,0 +1,97 @@
+"""Compile rehearsal: the main path's Pallas kernels, compiled for TPU v5e.
+
+Each test compiles (never runs) one kernel at the paper's widths — the
+largest MNIST map, side 40 (N = 1600) at D = 784, training batch 16 — for a
+described ``v5e:2x2`` topology, and asserts that the compiled program holds
+the Mosaic kernel (``tpu_custom_call``). What the TPU compiler refuses
+(tiling, unsupported primitives, VMEM) fails here without a chip.
+
+The topology is described only inside the module fixture, after a test of
+this file has started: the TPU compiler library admits one process at a
+time, so no import, ``skipif`` or ``parametrize`` may touch it. The
+persistent compilation cache is off around the compiles (entries written
+for a described chip cannot be read back without one).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.afm import AFMConfig
+from repro.kernels.bmu import ops as bmu_ops
+from repro.kernels.cascade.cascade import cascade_wave_pallas
+from repro.kernels.fused import fused as fused_lib
+from repro.kernels.fused import ops as fused_ops
+from repro.serving.maps import BmuEngine, CompileCache
+
+SIDE, DIM, BATCH = 40, 784, 16
+#: Largest side whose fused step fits the kernel's VMEM budget at DIM.
+FUSED_MAX_SIDE = 56
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure means no TPU lib
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+def _shape(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("precision", bmu_ops.PRECISIONS)
+def test_bmu_kernel_compiles_for_v5e(one_chip, precision):
+    _assert_kernel(
+        lambda w, s: bmu_ops.bmu(w, s, use_pallas=True, interpret=False,
+                                 precision=precision),
+        _shape(one_chip, (SIDE * SIDE, DIM)), _shape(one_chip, (BATCH, DIM)))
+
+
+def test_cascade_wave_kernel_compiles_for_v5e(one_chip):
+    lattice = _shape(one_chip, (SIDE, SIDE), jnp.int32)
+    _assert_kernel(lambda c, f, b: cascade_wave_pallas(c, f, b, 4),
+                   lattice, lattice,
+                   _shape(one_chip, (4, SIDE, SIDE), jnp.int32))
+
+
+def test_fused_step_compiles_for_v5e_at_its_largest_side(one_chip):
+    side = FUSED_MAX_SIDE
+    assert (fused_lib.vmem_bytes(side, DIM, fused_ops.DEFAULT_WAVE_CAP)
+            <= fused_lib.VMEM_LIMIT_BYTES)
+    cfg = AFMConfig(side=side, dim=DIM, batch=BATCH)
+    _assert_kernel(
+        lambda w, c, s, k: fused_ops.fused_step_parts(
+            w, c, s, k, cfg, l_c=0.5, p_i=0.3, use_pallas=True,
+            interpret=False),
+        _shape(one_chip, (side * side, DIM)),
+        _shape(one_chip, (side * side,), jnp.int32),
+        _shape(one_chip, (BATCH, DIM)),
+        _shape(one_chip, (2,), jnp.uint32))
+
+
+def test_bmu_engine_bucket_compiles_for_v5e(one_chip):
+    engine = BmuEngine(use_pallas=True, interpret=False, cache=CompileCache())
+    _assert_kernel(engine._call, _shape(one_chip, (SIDE * SIDE, DIM)),
+                   _shape(one_chip, (engine.buckets[2], DIM)))
+

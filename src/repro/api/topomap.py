@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api import backends as backends_lib
 from repro.core import classifier, metrics
 from repro.core.afm import AFMConfig, AFMState
@@ -84,17 +85,19 @@ class TopoMap:
         ``num_steps`` defaults to the config's full sample budget. Passing
         ``labels`` (num_samples,) also labels the units for ``predict``.
         """
-        data = jnp.asarray(data, jnp.float32)
-        key = jax.random.PRNGKey(self.seed) if key is None else key
-        k_init, k_run = jax.random.split(key)
-        state = self.backend.init(k_init, data)
-        state, aux = self.backend.run(state, data, k_run, num_steps)
-        self._backend_state = state
-        self.fit_aux_ = aux
-        self.state_ = self.backend.to_dense(state)
-        self._next_key = jax.random.fold_in(key, 0x5eed)
-        if labels is not None:
-            self.label(data, labels)
+        with obs.span(obs.TOPOMAP_FIT):
+            data = jnp.asarray(data, jnp.float32)
+            key = jax.random.PRNGKey(self.seed) if key is None else key
+            k_init, k_run = jax.random.split(key)
+            state = self.backend.init(k_init, data)
+            with obs.span(obs.BACKEND_RUN):
+                state, aux = self.backend.run(state, data, k_run, num_steps)
+            self._backend_state = state
+            self.fit_aux_ = aux
+            self.state_ = self.backend.to_dense(state)
+            self._next_key = jax.random.fold_in(key, 0x5eed)
+            if labels is not None:
+                self.label(data, labels)
         return self
 
     def partial_fit(self, batch, *, key: jax.Array | None = None) -> "TopoMap":
@@ -117,14 +120,15 @@ class TopoMap:
         """(Re)label units from a labelled sample set (paper Eq. 7 /
         majority vote, per the ``labeling`` setting)."""
         self._check_fitted()
-        data = jnp.asarray(data, jnp.float32)
-        labels = jnp.asarray(labels, jnp.int32)
-        if self.labeling == "majority":
-            self.unit_labels_ = classifier.label_units_majority(
-                self.state_.w, data, labels, num_classes)
-        else:
-            self.unit_labels_ = classifier.label_units(self.state_.w, data,
-                                                       labels)
+        with obs.span(obs.TOPOMAP_LABEL):
+            data = jnp.asarray(data, jnp.float32)
+            labels = jnp.asarray(labels, jnp.int32)
+            if self.labeling == "majority":
+                self.unit_labels_ = classifier.label_units_majority(
+                    self.state_.w, data, labels, num_classes)
+            else:
+                self.unit_labels_ = classifier.label_units(
+                    self.state_.w, data, labels)
         return self
 
     @classmethod

@@ -39,6 +39,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import cascade as cascade_lib
 from repro.core import links, schedules
 from repro.core import search as search_lib
@@ -213,9 +214,12 @@ def _step(state: AFMState, samples: jnp.ndarray, key: jax.Array,
     l_c = schedules.cascade_learning_rate(i, cfg.total_samples, cfg.c_o, cfg.c_s)
     p_i = schedules.cascade_probability(i, cfg.total_samples, n, cfg.c_m, cfg.c_d)
 
-    res = stages.search(state, samples, k_search, cfg)
-    w, counts = stages.adapt(state, samples, res.gmu, cfg)
-    out = stages.cascade(w, state.c, counts, l_c, p_i, k_cascade, cfg)
+    with jax.named_scope(obs.AFM_SEARCH):
+        res = stages.search(state, samples, k_search, cfg)
+    with jax.named_scope(obs.AFM_ADAPT):
+        w, counts = stages.adapt(state, samples, res.gmu, cfg)
+    with jax.named_scope(obs.AFM_CASCADE):
+        out = stages.cascade(w, state.c, counts, l_c, p_i, k_cascade, cfg)
 
     new_state = AFMState(
         w=out.w.reshape(n, cfg.dim),

@@ -64,6 +64,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import afm as afm_lib
 from repro.core import cascade as cascade_lib
 from repro.core import schedules
@@ -371,7 +372,8 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
     src4, dst4, dirs4 = placement.routing(near)
 
     def pool_min(es: EventState):
-        return selector(es.msg_t, es.msg_key, es.msg_gen, es.msg_cid)
+        with jax.named_scope(obs.EVENTS_POOL):
+            return selector(es.msg_t, es.msg_key, es.msg_gen, es.msg_cid)
 
     def fire(es: EventState, fired, cid, t, gen) -> EventState:
         """Broadcast-after-theta: ``fired`` units reset their counters and
@@ -448,9 +450,10 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
                     drop0 + dropped, sent0, dfault0, fkey)
 
         # most rounds fire nothing: skip the pool scatters entirely then
-        (msg_t, msg_key, msg_gen, msg_cid, msg_dst, msg_dir, msg_w,
-         free_head, free_n, dropped, sent, dfault, fault_key) = jax.lax.cond(
-            nfired > 0, enqueue, lambda p: p, pool)
+        with jax.named_scope(obs.EVENTS_POOL):
+            (msg_t, msg_key, msg_gen, msg_cid, msg_dst, msg_dir, msg_w,
+             free_head, free_n, dropped, sent, dfault,
+             fault_key) = jax.lax.cond(nfired > 0, enqueue, lambda p: p, pool)
         return es._replace(
             c=c, sizes=sizes, lat_key=lat_key,
             msg_t=msg_t, msg_key=msg_key, msg_gen=msg_gen, msg_cid=msg_cid,
@@ -537,12 +540,13 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         k_wave = es.wcount[cid] + 1
         bern = (jax.random.uniform(sub, (4, side, side)) < p_i).reshape(4, n)
         # compress the selected messages: (k_round,) slot ids, fill = m
-        idx = jnp.nonzero(sel, size=k_round, fill_value=m)[0]
-        ok = idx < m
-        ii = jnp.minimum(idx, m - 1)
-        dsts = jnp.where(ok, es.msg_dst[ii], n)          # n -> dropped row
-        dirs = jnp.where(ok, es.msg_dir[ii], 0)
-        ws = es.msg_w[ii]                                # (k_round, D)
+        with jax.named_scope(obs.EVENTS_POOL):
+            idx = jnp.nonzero(sel, size=k_round, fill_value=m)[0]
+            ok = idx < m
+            ii = jnp.minimum(idx, m - 1)
+            dsts = jnp.where(ok, es.msg_dst[ii], n)      # n -> dropped row
+            dirs = jnp.where(ok, es.msg_dir[ii], 0)
+            ws = es.msg_w[ii]                            # (k_round, D)
         if dead_on:
             # messages addressed to a dead unit are consumed (their slots
             # free normally) but not delivered: no adapt, no drive, no
@@ -580,15 +584,19 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         else:
             ndeliv = nsel
         # free the delivered slots: push their ids onto the ring tail
-        freed_rank = jnp.cumsum(sel.astype(jnp.int32)) - 1
-        tail = jnp.where(sel, (es.free_head + es.free_n + freed_rank) % m, m)
+        with jax.named_scope(obs.EVENTS_POOL):
+            freed_rank = jnp.cumsum(sel.astype(jnp.int32)) - 1
+            tail = jnp.where(sel, (es.free_head + es.free_n + freed_rank) % m,
+                             m)
+            msg_t = jnp.where(sel, jnp.inf, es.msg_t)
+            free_ring = es.free_ring.at[tail].set(
+                jnp.arange(m, dtype=jnp.int32), mode="drop")
         es = es._replace(
             w=w, c=c, t=tmin,
             clock=jnp.where(received, tmin, es.clock),
             nevents=es.nevents + n_recv,
-            msg_t=jnp.where(sel, jnp.inf, es.msg_t),
-            free_ring=es.free_ring.at[tail].set(
-                jnp.arange(m, dtype=jnp.int32), mode="drop"),
+            msg_t=msg_t,
+            free_ring=free_ring,
             free_n=es.free_n + nsel,
             casc_key=es.casc_key.at[cid].set(ck),
             wcount=es.wcount.at[cid].set(k_wave),

@@ -91,6 +91,7 @@ def bmu_pallas(w: jnp.ndarray, s: jnp.ndarray, *, block_b: int = 128,
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="bmu_pallas",
     )(w, s, w2)
     s2 = jnp.sum(s.astype(jnp.float32) ** 2, axis=-1)
     return idx_out[:, 0], jnp.maximum(min_out[:, 0] + s2, 0.0)

@@ -79,5 +79,6 @@ def cascade_wave_pallas(c: jnp.ndarray, fired: jnp.ndarray, bern: jnp.ndarray,
             jax.ShapeDtypeStruct((n, n), jnp.int32),
         ],
         interpret=interpret,
+        name="cascade_wave_pallas",
     )(c.astype(jnp.int32), fired.astype(jnp.int32), bern.astype(jnp.int32))
     return new_c, new_fired.astype(bool), recv

@@ -289,6 +289,7 @@ def fused_step_pallas(w, c2, s, scal, drive, bern, gmu=None, *, theta: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_step_pallas",
     )(*args)
     if has_search:  # lint: tracer-ok(static arg-presence flag)
         return out

@@ -26,6 +26,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import afm as afm_lib
 from repro.core import schedules
 from repro.core import search as search_lib
@@ -127,18 +128,18 @@ def fused_step_parts(w, c, samples, k_cascade, cfg, *, l_c, p_i,
     # ---- kernel path: precompute the PRNG, run the megakernel, finish any
     # over-budget cascade with the oracle's tail loop from chain position
     # ``wave_cap`` (the kernel consumed draws 0..wave_cap-1)
-    k_drive, k_chain = jax.random.split(k_cascade)
-    draws = jax.random.uniform(k_drive, (8, side, side)) < p_i
-
     def chain(k, _):
         k, sub = jax.random.split(k)
         return k, sub
 
-    k_after, subs = jax.lax.scan(chain, k_chain, None, length=wave_cap)
-    # vmap over explicit per-wave keys is bitwise-identical to drawing
-    # inside the loop (the ``search.exploration_phase`` precedent)
-    bern = jax.vmap(
-        lambda sk: jax.random.uniform(sk, (4, side, side)) < p_i)(subs)
+    with jax.named_scope(obs.FUSED_WAVE_KEYS):
+        k_drive, k_chain = jax.random.split(k_cascade)
+        draws = jax.random.uniform(k_drive, (8, side, side)) < p_i
+        k_after, subs = jax.lax.scan(chain, k_chain, None, length=wave_cap)
+        # vmap over explicit per-wave keys is bitwise-identical to drawing
+        # inside the loop (the ``search.exploration_phase`` precedent)
+        bern = jax.vmap(
+            lambda sk: jax.random.uniform(sk, (4, side, side)) < p_i)(subs)
 
     scal = jnp.stack([jnp.float32(cfg.l_s), jnp.asarray(l_c, jnp.float32)])
     budget = min(wave_cap, max_waves)

@@ -42,6 +42,7 @@ from concurrent.futures import Future
 
 import numpy as np
 
+from repro import obs
 from repro.serving.maps import DEFAULT_BUCKETS, MapService, postprocess
 
 _KINDS = ("transform", "predict", "quantization_errors")
@@ -64,7 +65,10 @@ class GatewayStats:
     ``dispatches``/``dispatch_samples``/``dispatch_requests`` cover the
     coalescer only; ``direct`` counts large requests served inline. A mean
     dispatch size above 1 is the coalescing win: that many requests rode
-    one padded BMU call.
+    one padded BMU call. ``queued_s`` sums, over the coalesced requests,
+    the wait from enqueue to the start of the dispatch that took each one:
+    ``queued_s / dispatch_requests`` is the mean time a request spends in
+    the queue.
     """
     requests: int = 0            # everything submitted
     samples: int = 0
@@ -72,6 +76,7 @@ class GatewayStats:
     dispatches: int = 0          # coalesced engine dispatches
     dispatch_samples: int = 0
     dispatch_requests: int = 0
+    queued_s: float = 0.0        # summed enqueue -> dispatch-start waits
     max_dispatch: int = 0        # largest merged sample count
 
     def mean_dispatch_size(self) -> float:
@@ -403,47 +408,62 @@ class MapGateway:
 
     def _dispatch(self, name: str, group: list[_Pending]) -> None:
         del name
-        try:
-            # the service each request was validated against at submit time
-            # — a shape-changing reload() mid-queue must not retarget them
-            svc = group[0].svc
-            merged = (group[0].data if len(group) == 1 else
-                      np.concatenate([p.data for p in group], axis=0))
-            idx, q2, labels = self._serve_bmu(svc, merged)
-            # materialise once per dispatch; per-request slicing is then
-            # free numpy views, with no further jax dispatches
-            idx = np.asarray(idx)
-            q2 = np.asarray(q2)
-            labels = None if labels is None else np.asarray(labels)
-        except BaseException as e:          # noqa: BLE001 — goes to callers
-            for pending in group:
-                self._resolve(pending, exc=e)
-            return
-        total = int(merged.shape[0])
-        with self._cond:
-            st = self.stats
-            st.dispatches += 1
-            st.dispatch_samples += total
-            st.dispatch_requests += len(group)
-            st.max_dispatch = max(st.max_dispatch, total)
-        lo = 0
-        for pending in group:
-            sl = slice(lo, lo + pending.size)
-            lo += pending.size
+        start = time.perf_counter()
+        total = sum(p.size for p in group)
+        with obs.span(obs.GATEWAY_DISPATCH, requests=len(group), rows=total):
             try:
-                self._resolve(pending, self._post(svc, pending, idx[sl],
-                                                  q2[sl], labels))
-            except BaseException as e:      # noqa: BLE001 — goes to caller
-                self._resolve(pending, exc=e)
+                # the service each request was validated against at submit
+                # time — a shape-changing reload() mid-queue must not
+                # retarget them
+                svc = group[0].svc
+                with obs.span(obs.GATEWAY_MERGE):
+                    merged = (group[0].data if len(group) == 1 else
+                              np.concatenate([p.data for p in group], axis=0))
+                idx, q2, labels = self._serve_bmu(svc, merged)
+            except BaseException as e:      # noqa: BLE001 — goes to callers
+                self._fail(group, e)
+                return
+            with obs.span(obs.GATEWAY_RESOLVE):
+                try:
+                    # materialise once per dispatch; per-request slicing is
+                    # then free numpy views, with no further jax dispatches
+                    idx = np.asarray(idx)
+                    q2 = np.asarray(q2)
+                    labels = None if labels is None else np.asarray(labels)
+                except BaseException as e:  # noqa: BLE001 — goes to callers
+                    self._fail(group, e)
+                    return
+                with self._cond:
+                    st = self.stats
+                    st.dispatches += 1
+                    st.dispatch_samples += total
+                    st.dispatch_requests += len(group)
+                    st.queued_s += sum(start - p.t_enq for p in group)
+                    st.max_dispatch = max(st.max_dispatch, total)
+                lo = 0
+                for pending in group:
+                    sl = slice(lo, lo + pending.size)
+                    lo += pending.size
+                    try:
+                        self._resolve(pending, self._post(
+                            svc, pending, idx[sl], q2[sl], labels))
+                    except BaseException as e:  # noqa: BLE001 — to caller
+                        self._resolve(pending, exc=e)
+
+    def _fail(self, group: list[_Pending], exc: BaseException) -> None:
+        for pending in group:
+            self._resolve(pending, exc=exc)
 
     def _serve_inline(self, svc: MapService, pending: _Pending) -> None:
-        try:
-            idx, q2, labels = self._serve_bmu(svc, pending.data)
-            self._resolve(pending, self._post(
-                svc, pending, np.asarray(idx), np.asarray(q2),
-                None if labels is None else np.asarray(labels)))
-        except BaseException as e:          # noqa: BLE001 — goes to caller
-            self._resolve(pending, exc=e)
+        with obs.span(obs.GATEWAY_DISPATCH, requests=1, rows=pending.size):
+            try:
+                idx, q2, labels = self._serve_bmu(svc, pending.data)
+                with obs.span(obs.GATEWAY_RESOLVE):
+                    self._resolve(pending, self._post(
+                        svc, pending, np.asarray(idx), np.asarray(q2),
+                        None if labels is None else np.asarray(labels)))
+            except BaseException as e:      # noqa: BLE001 — goes to caller
+                self._resolve(pending, exc=e)
 
     @staticmethod
     def _post(svc: MapService, pending: _Pending, idx, q2, labels):

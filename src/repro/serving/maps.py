@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import metrics
 from repro.core import search as search_lib
 from repro.core.afm import AFMConfig, AFMState
@@ -340,11 +341,6 @@ class ServiceStats:
     latency: LatencyHistogram = dataclasses.field(
         default_factory=LatencyHistogram)
 
-    @property
-    def seconds(self) -> float:
-        """Back-compat alias for ``busy_seconds``."""
-        return self.busy_seconds
-
     def window_seconds(self) -> float:
         if self.window_start is None or self.window_end is None:
             return 0.0
@@ -507,8 +503,9 @@ class MapService:
 
     def _serve(self, w, data):
         t0 = time.perf_counter()
-        idx, q2 = self.engine.bmu(w, data)
-        idx = jax.block_until_ready(idx)
+        with obs.span(obs.ENGINE_BMU):
+            idx, q2 = self.engine.bmu(w, data)
+            idx = jax.block_until_ready(idx)
         t1 = time.perf_counter()          # span ends before any lock wait
         with self._lock:
             st = self.stats

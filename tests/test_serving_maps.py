@@ -299,7 +299,6 @@ def test_stats_track_busy_and_wall_window(fitted):
     s = svc.stats
     assert s.requests == 2 and s.samples == 48
     assert s.busy_seconds > 0
-    assert s.seconds == s.busy_seconds          # back-compat alias
     # the window spans both requests including the gap between them, so it
     # is at least as long as the summed sequential spans
     assert s.window_seconds() >= s.busy_seconds

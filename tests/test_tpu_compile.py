@@ -55,23 +55,28 @@ def _shape(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(fn, name, *args):
+    """The compiled program holds the Mosaic kernel as op ``name`` (the
+    name a device trace shows it by)."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert calls
+    assert any(line.startswith(f"%{name}") for line in calls), calls
 
 
 @pytest.mark.parametrize("precision", bmu_ops.PRECISIONS)
 def test_bmu_kernel_compiles_for_v5e(one_chip, precision):
     _assert_kernel(
         lambda w, s: bmu_ops.bmu(w, s, use_pallas=True, interpret=False,
-                                 precision=precision),
+                                 precision=precision), "bmu_pallas",
         _shape(one_chip, (SIDE * SIDE, DIM)), _shape(one_chip, (BATCH, DIM)))
 
 
 def test_cascade_wave_kernel_compiles_for_v5e(one_chip):
     lattice = _shape(one_chip, (SIDE, SIDE), jnp.int32)
     _assert_kernel(lambda c, f, b: cascade_wave_pallas(c, f, b, 4),
-                   lattice, lattice,
+                   "cascade_wave_pallas", lattice, lattice,
                    _shape(one_chip, (4, SIDE, SIDE), jnp.int32))
 
 
@@ -83,7 +88,7 @@ def test_fused_step_compiles_for_v5e_at_its_largest_side(one_chip):
     _assert_kernel(
         lambda w, c, s, k: fused_ops.fused_step_parts(
             w, c, s, k, cfg, l_c=0.5, p_i=0.3, use_pallas=True,
-            interpret=False),
+            interpret=False), "fused_step_pallas",
         _shape(one_chip, (side * side, DIM)),
         _shape(one_chip, (side * side,), jnp.int32),
         _shape(one_chip, (BATCH, DIM)),
@@ -92,6 +97,7 @@ def test_fused_step_compiles_for_v5e_at_its_largest_side(one_chip):
 
 def test_bmu_engine_bucket_compiles_for_v5e(one_chip):
     engine = BmuEngine(use_pallas=True, interpret=False, cache=CompileCache())
-    _assert_kernel(engine._call, _shape(one_chip, (SIDE * SIDE, DIM)),
+    _assert_kernel(engine._call, "bmu_pallas",
+                   _shape(one_chip, (SIDE * SIDE, DIM)),
                    _shape(one_chip, (engine.buckets[2], DIM)))
 

@@ -86,6 +86,28 @@ _key_scale = placement_single.key_scale
 _pool_min_lex = placement_single.pool_min_lex
 _pool_min_packed = placement_single.pool_min_packed
 
+#: Static width of a delivery round that selects few messages. An
+#: exponential-latency round delivers one message and a constant-latency one
+#: the output of one ``fire()`` (≤ 4 per fired unit), so 64 covers every
+#: exponential round and a fire of up to 16 units; wider rounds run at the
+#: worst-case width ``k_round``.
+_NARROW_WIDTH = 64
+
+
+def _compress(mask, width: int):
+    """Ascending positions of the first ``width`` True entries of the 1-D
+    ``mask``, filled with ``mask.size``: ``jnp.nonzero(mask, size=width,
+    fill_value=mask.size)[0]``. ``jnp.nonzero`` counts the entries with a
+    scatter-add over all of them, which the TPU runs serially (about 116 µs
+    at 12,800 entries on a v5e); at a narrow width, the j-th position is
+    the count of entries whose running True count is at most j, one fused
+    ``width × size`` compare and sum (about 3 µs there)."""
+    if width > _NARROW_WIDTH:
+        return jnp.nonzero(mask, size=width, fill_value=mask.size)[0]
+    rank = jnp.cumsum(mask, dtype=jnp.int32)
+    return jnp.sum(rank[None, :] <= jnp.arange(width, dtype=jnp.int32)[:, None],
+                   axis=1, dtype=jnp.int32)
+
 #: Direction codes, from the *receiver*'s perspective, matching the slot
 #: order of ``core.cascade._shift4``: 0 = from row+1 (below), 1 = from row-1
 #: (above), 2 = from col+1 (right), 3 = from col-1 (left). A sender's 4
@@ -237,6 +259,7 @@ class EventState(NamedTuple):
     dropped_fault: jnp.ndarray  # () i32 — injected losses + dead receivers
     samples_dead: jnp.ndarray  # () i32 — samples routed to a dead GMU
     fault_key: jnp.ndarray     # (2,) u32 — the plan's own PRNG stream
+    narrow_rounds: jnp.ndarray  # () i32 — delivery rounds run at k_narrow
 
 
 class EventReport(NamedTuple):
@@ -265,6 +288,9 @@ class EventReport(NamedTuple):
     shard_counts: jnp.ndarray = 0  # (K, 5) i32 — per-shard [sent, delivered,
     #                                dropped_overflow, dropped_fault,
     #                                stranded]; K=1 off-mesh
+    narrow_rounds: jnp.ndarray = 0  # () i32 — delivery rounds served at the
+    #                                narrow width (hit share: narrow_rounds /
+    #                                (rounds - samples))
 
     @property
     def events(self):
@@ -320,6 +346,7 @@ def init_events(state: AFMState, cfg: AFMConfig, ecfg: EventConfig,
         samples_dead=jnp.int32(0),
         fault_key=(jax.random.PRNGKey(ecfg.plan.seed)
                    if ecfg.fault_active else z((2,), jnp.uint32)),
+        narrow_rounds=jnp.int32(0),
     )
 
 
@@ -367,8 +394,10 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
     selector = placement.make_selector(cfg, ecfg, num_events)
     # a delivery round selects one (t, gen, cid): at zero/constant latency
     # that is one fire()'s output (≤ 4N messages); exponential delays can in
-    # principle tie across fires, so the selection width covers the pool
+    # principle tie across fires, so the selection width covers the pool.
+    # Rounds that select at most k_narrow messages run at that width instead.
     k_round = m if ecfg.latency == "exponential" else k_sel
+    k_narrow = min(k_round, _NARROW_WIDTH)
     src4, dst4, dirs4 = placement.routing(near)
 
     def pool_min(es: EventState):
@@ -524,13 +553,17 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         receiver adapts by the merged rule, is Bernoulli-driven once per
         received message, and newly super-threshold receivers fire.
 
-        Work is sized by the round, not the map: the ≤``k_round`` selected
-        slots are compressed out of the pool, their payloads segment-summed
-        per receiver in direction-slot order (bitwise the same sum order as
+        Work is sized by the round, not the map: the selected slots are
+        compressed out of the pool, their payloads segment-summed per
+        receiver in direction-slot order (bitwise the same sum order as
         ``core.cascade._shift_sum``), and the weight update is a row scatter
-        over the ≤``k_round`` receiver units. The (4, side, side) Bernoulli
-        tensor still comes whole from the cascade's own key chain — PRNG
-        shapes are part of the bitwise contract.
+        over the receiver units. The static width of that work is
+        ``k_narrow`` when the round's own count allows it (every exponential
+        round, most constant ones) and ``k_round`` otherwise; both widths
+        add the same operands in the same order, so the choice never shows
+        in the result. The (4, side, side) Bernoulli tensor still comes
+        whole from the cascade's own key chain — PRNG shapes are part of
+        the bitwise contract.
         """
         cid = cmin
         sched_i = i0 + cid
@@ -539,58 +572,77 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         ck, sub = jax.random.split(es.casc_key[cid])
         k_wave = es.wcount[cid] + 1
         bern = (jax.random.uniform(sub, (4, side, side)) < p_i).reshape(4, n)
-        # compress the selected messages: (k_round,) slot ids, fill = m
-        with jax.named_scope(obs.EVENTS_POOL):
-            idx = jnp.nonzero(sel, size=k_round, fill_value=m)[0]
-            ok = idx < m
-            ii = jnp.minimum(idx, m - 1)
-            dsts = jnp.where(ok, es.msg_dst[ii], n)      # n -> dropped row
-            dirs = jnp.where(ok, es.msg_dir[ii], 0)
-            ws = es.msg_w[ii]                            # (k_round, D)
-        if dead_on:
-            # messages addressed to a dead unit are consumed (their slots
-            # free normally) but not delivered: no adapt, no drive, no
-            # clock/event stamp — they count as ``dropped_fault``
-            ok = ok & ~dead_at(tmin)[jnp.minimum(dsts, n - 1)]
-        # counter drive: one Bernoulli per received message, from the wave's
-        # (4, N) tensor indexed by (direction, receiver)
-        drive = jnp.where(ok, bern[dirs, jnp.minimum(dsts, n - 1)], False)
-        c = es.c.at[dsts].add(drive.astype(jnp.int32), mode="drop")
-        n_recv = jnp.zeros((n,), jnp.int32).at[dsts].add(
-            ok.astype(jnp.int32), mode="drop")
-        received = n_recv > 0
-        # unique receiver rows (sorted, fill = n), ≤ one per message
-        ridx = jnp.nonzero(received, size=k_round, fill_value=n)[0]
-        pos = jnp.searchsorted(ridx, dsts)               # msg -> receiver row
-        acc = jnp.zeros((k_round, d), jnp.float32)
-        for s4 in range(4):                              # direction-slot order
-            acc = acc.at[jnp.where(ok & (dirs == s4), pos, k_round)].add(
-                ws, mode="drop")
-        # full receiver rows via the same elementwise chain as the dense
-        # form (w + l_c*(S - nf*w)) so XLA emits the same fma pattern, then
-        # a row scatter-set (ridx rows are unique)
-        rv = jnp.minimum(ridx, n - 1)
-        nf = n_recv[rv].astype(es.w.dtype)
-        wr = es.w[rv]
-        w_rows = wr + l_c * (acc - nf[:, None] * wr)
-        w = es.w.at[ridx].set(w_rows, mode="drop")
         nsel = jnp.sum(sel, dtype=jnp.int32)
+
+        def receive(width, _=None):
+            """The receiver side at static ``width`` ≥ ``nsel``: returns
+            (w, c, n_recv, ndeliv, free_ring)."""
+            # compress the selected messages: (width,) slot ids, fill = m
+            with jax.named_scope(obs.EVENTS_POOL):
+                idx = _compress(sel, width)
+                taken = idx < m
+                ii = jnp.minimum(idx, m - 1)
+                dsts = jnp.where(taken, es.msg_dst[ii], n)  # n -> dropped
+                dirs = jnp.where(taken, es.msg_dir[ii], 0)
+                ws = es.msg_w[ii]                           # (width, D)
+            with jax.named_scope(obs.EVENTS_DELIVER):
+                ok = taken
+                if dead_on:
+                    # messages addressed to a dead unit are consumed
+                    # (their slots free normally) but not delivered: no
+                    # adapt, no drive, no clock/event stamp — they count
+                    # as ``dropped_fault``
+                    ok = ok & ~dead_at(tmin)[jnp.minimum(dsts, n - 1)]
+                # counter drive: one Bernoulli per received message,
+                # from the wave's (4, N) tensor by (direction, receiver)
+                drive = jnp.where(ok, bern[dirs, jnp.minimum(dsts, n - 1)],
+                                  False)
+                c = es.c.at[dsts].add(drive.astype(jnp.int32), mode="drop")
+                n_recv = jnp.zeros((n,), jnp.int32).at[dsts].add(
+                    ok.astype(jnp.int32), mode="drop")
+                # unique receiver rows (sorted, fill = n), ≤ one per message
+                ridx = _compress(n_recv > 0, width)
+                pos = jnp.searchsorted(ridx, dsts)      # msg -> receiver row
+                acc = jnp.zeros((width, d), jnp.float32)
+                for s4 in range(4):                     # direction-slot order
+                    acc = acc.at[jnp.where(ok & (dirs == s4), pos, width)
+                                 ].add(ws, mode="drop")
+                # full receiver rows via the same elementwise chain as the
+                # dense form (w + l_c*(S - nf*w)) so XLA emits the same fma
+                # pattern, then a row scatter-set (ridx rows are unique)
+                rv = jnp.minimum(ridx, n - 1)
+                nf = n_recv[rv].astype(es.w.dtype)
+                wr = es.w[rv]
+                w_rows = wr + l_c * (acc - nf[:, None] * wr)
+                w = es.w.at[ridx].set(w_rows, mode="drop")
+            # ``ok`` excludes dead receivers; the gap vs the nsel consumed
+            # slots is the dead-receiver fault count
+            ndeliv = jnp.sum(ok, dtype=jnp.int32) if dead_on else nsel
+            # free the taken slots (dead receivers' too): push their ids
+            # onto the ring tail, in ascending slot order
+            with jax.named_scope(obs.EVENTS_POOL):
+                tail = jnp.where(
+                    taken,
+                    (es.free_head + es.free_n
+                     + jnp.arange(width, dtype=jnp.int32)) % m, m)
+                free_ring = es.free_ring.at[tail].set(idx, mode="drop")
+            return w, c, n_recv, ndeliv, free_ring
+
+        if k_narrow < k_round:
+            narrow = nsel <= k_narrow
+            w, c, n_recv, ndeliv, free_ring = jax.lax.cond(
+                narrow, functools.partial(receive, k_narrow),
+                functools.partial(receive, k_round), None)
+            narrow_rounds = es.narrow_rounds + narrow.astype(jnp.int32)
+        else:
+            w, c, n_recv, ndeliv, free_ring = receive(k_round)
+            narrow_rounds = es.narrow_rounds
+        received = n_recv > 0
         extra = {}
         if dead_on:
-            # ``ok`` already excludes dead receivers; the gap vs the nsel
-            # consumed slots is the dead-receiver fault count
-            ndeliv = jnp.sum(ok, dtype=jnp.int32)
             extra["dropped_fault"] = es.dropped_fault + (nsel - ndeliv)
-        else:
-            ndeliv = nsel
-        # free the delivered slots: push their ids onto the ring tail
         with jax.named_scope(obs.EVENTS_POOL):
-            freed_rank = jnp.cumsum(sel.astype(jnp.int32)) - 1
-            tail = jnp.where(sel, (es.free_head + es.free_n + freed_rank) % m,
-                             m)
             msg_t = jnp.where(sel, jnp.inf, es.msg_t)
-            free_ring = es.free_ring.at[tail].set(
-                jnp.arange(m, dtype=jnp.int32), mode="drop")
         es = es._replace(
             w=w, c=c, t=tmin,
             clock=jnp.where(received, tmin, es.clock),
@@ -602,6 +654,7 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
             wcount=es.wcount.at[cid].set(k_wave),
             deliveries=es.deliveries + ndeliv,
             rounds=es.rounds + 1,
+            narrow_rounds=narrow_rounds,
             **extra,
         )
         new_fired = (c >= theta) & received
@@ -628,7 +681,8 @@ def _finish(es: EventState, far, near):
         sent=es.sent, dropped_fault=es.dropped_fault, stranded=stranded,
         samples_dead=es.samples_dead,
         shard_counts=jnp.stack([es.sent, es.deliveries, es.dropped,
-                                es.dropped_fault, stranded])[None, :])
+                                es.dropped_fault, stranded])[None, :],
+        narrow_rounds=es.narrow_rounds)
     return final, aux, report
 
 
@@ -957,7 +1011,8 @@ def run_events(state: AFMState, samples: jnp.ndarray, step_keys: jnp.ndarray,
                 jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.int32),
                 sent=zero, dropped_fault=zero, stranded=zero,
                 samples_dead=zero,
-                shard_counts=jnp.zeros((1, 5), jnp.int32))
+                shard_counts=jnp.zeros((1, 5), jnp.int32),
+                narrow_rounds=zero)
     if lat_key is None:
         lat_key = jax.random.PRNGKey(lat_seed)
     pl = placement_base.resolve_placement(placement, shards=shards)

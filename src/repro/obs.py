@@ -21,8 +21,10 @@ Host spans (nesting shown by indentation):
 
 Device scopes: ``afm.search``, ``afm.adapt`` and ``afm.cascade`` (the
 staged step's three stages), ``fused.wave_keys`` (the fused step's wave-key
-chain and Bernoulli draws) and ``events.pool`` (the event engine's message
-pool: selection, enqueue, and the delivery round's take and clear).
+chain and Bernoulli draws), ``events.pool`` (the event engine's message
+pool: selection, enqueue, and the delivery round's take and clear) and
+``events.deliver`` (the delivery round's receiver side: drive, per-receiver
+segment sums, weight rows).
 """
 from __future__ import annotations
 
@@ -41,6 +43,7 @@ AFM_ADAPT = "afm.adapt"
 AFM_CASCADE = "afm.cascade"
 FUSED_WAVE_KEYS = "fused.wave_keys"
 EVENTS_POOL = "events.pool"
+EVENTS_DELIVER = "events.deliver"
 
 
 def span(name: str, **metadata):

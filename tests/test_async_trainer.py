@@ -36,6 +36,7 @@ from repro.api import AFMConfig, TopoMap, available_backends, get_backend
 from repro.core import afm, events, sandpile
 from repro.core import search as search_lib
 from repro.data import make_dataset
+from repro.faults import FaultPlan
 from repro.launch.stream_train import run_stream
 
 CFG = AFMConfig(side=6, dim=12, i_max=48, batch=1, e_factor=0.5)
@@ -414,6 +415,108 @@ def test_round_semantics_match_pre_optimization_golden(case, variant):
     _REGEN.assert_matches_golden(out, gold, case, f"({variant})")
 
 
+#: Exponential latency with a live dropout window: messages addressed to
+#: dead units are consumed by the delivery round but not delivered.
+_DROPOUT = FaultPlan(seed=5, dropout_frac=0.25, dropout_start=10.0,
+                     dropout_len=40.0)
+_WIDTH_CASES = {name: _CASE_BY_NAME[name]
+                for name in ("hot_const", "hot_exp", "tiny_pool")}
+_WIDTH_CASES["hot_exp_dropout"] = _CASE_BY_NAME["hot_exp"][:2] + (
+    dict(_CASE_BY_NAME["hot_exp"][2], faults=_DROPOUT), True)
+
+
+@pytest.fixture
+def narrow_width(monkeypatch):
+    """Sets the event engine's narrow delivery width; compiled runners are
+    dropped on each change and after the test, so none built at a patched
+    width outlives it."""
+    def set_width(width):
+        monkeypatch.setattr(events, "_NARROW_WIDTH", width)
+        events._compiled_runner.cache_clear()
+    yield set_width
+    events._compiled_runner.cache_clear()
+
+
+@pytest.mark.parametrize("case", sorted(_WIDTH_CASES))
+def test_narrow_and_wide_delivery_rounds_agree_bitwise(case, narrow_width,
+                                                       monkeypatch):
+    """A delivery round run at the narrow width gives bit for bit what the
+    worst-case width gives. Width 4 (one fired unit's broadcast) sends every
+    exponential round and a constant-latency round of one fired unit down
+    the narrow branch, the rest down the wide one; the pool's size sends
+    every round down the wide one."""
+    cfg, num_events, ekw, hot = _WIDTH_CASES[case]
+    m = events._resolve(cfg, events.EventConfig(**ekw), num_events)[0]
+    reports = []
+    run_events = events.run_events
+
+    def recording(*args, **kwargs):
+        out = run_events(*args, **kwargs)
+        reports.append(out[2])
+        return out
+
+    monkeypatch.setattr(events, "run_events", recording)
+    outs = []
+    for width in (4, m):
+        narrow_width(width)
+        with jax.threefry_partitionable(False):
+            outs.append(_REGEN.run_case(cfg, num_events, ekw, hot))
+    narrow, wide = outs
+    for k in wide:
+        np.testing.assert_array_equal(narrow[k], wide[k], err_msg=k)
+    rep_narrow, rep_wide = reports
+    assert int(rep_wide.narrow_rounds) == 0
+    assert int(rep_narrow.narrow_rounds) > 0
+    for rep in reports:
+        assert int(rep.sent) == (int(rep.deliveries) + int(rep.dropped_overflow)
+                                 + int(rep.dropped_fault) + int(rep.stranded))
+    if "faults" in ekw:
+        assert int(rep_narrow.dropped_fault) == int(rep_wide.dropped_fault) > 0
+
+
+@pytest.mark.parametrize("width", [1, 4, events._NARROW_WIDTH])
+def test_compress_matches_nonzero(width):
+    """At the narrow widths ``_compress`` counts ranks in place of calling
+    ``jnp.nonzero``, and equals it (the mask's size as fill) with fewer, as
+    many and more True entries than ``width``."""
+    rng = np.random.default_rng(width)
+    for size, ntrue in ((300, 0), (300, width), (300, 3 * width), (300, 300)):
+        mask = np.zeros(size, bool)
+        mask[rng.choice(size, min(ntrue, size), replace=False)] = True
+        want = jnp.nonzero(mask, size=width, fill_value=size)[0]
+        np.testing.assert_array_equal(events._compress(jnp.asarray(mask),
+                                                       width), want)
+
+
+def test_narrow_rounds_count_the_delivery_rounds():
+    """Every exponential-latency delivery round delivers one message and
+    runs narrow; constant-latency rounds run narrow when one fire's output
+    fits the narrow width."""
+    def report(case):
+        cfg, num_events, ekw, hot = _CASE_BY_NAME[case]
+        with jax.threefry_partitionable(False):
+            state = afm.init(jax.random.PRNGKey(0), cfg)
+            x = jax.random.normal(jax.random.PRNGKey(1),
+                                  (num_events, cfg.dim))
+            return events.run_events(
+                state, x, jax.random.split(jax.random.PRNGKey(2), num_events),
+                cfg, events.EventConfig(**ekw),
+                p_fn=_REGEN._p_hot if hot else events._default_p)[2]
+
+    exp = report("hot_exp")
+    delivery_rounds = int(exp.rounds) - int(exp.samples)
+    assert delivery_rounds > 0
+    assert int(exp.narrow_rounds) == delivery_rounds
+    assert int(exp.deliveries) == delivery_rounds
+    const = report("hot_const")
+    assert 0 < int(const.narrow_rounds) <= int(const.rounds) - int(
+        const.samples)
+    _, _, empty = events.run_events(
+        afm.init(jax.random.PRNGKey(0), CFG), jnp.zeros((0, CFG.dim)),
+        jnp.zeros((0, 2), jnp.uint32), CFG)
+    assert int(empty.narrow_rounds) == 0
+
+
 def test_zero_fast_path_dispatch_conditions():
     """The fused scan only takes over when it is provably equivalent."""
     ok = events._zero_fast_ok
@@ -525,7 +628,8 @@ def test_packed_key_and_lex_fallback_agree_bitwise():
 def test_zero_fast_path_equals_engine_on_seeded_10x10():
     """Live invariant behind the fast path: on a seeded 10x10 run the fused
     scan and the forced discrete-event engine agree bitwise — state, aux,
-    and the EventReport field for field."""
+    and the EventReport field for field. ``narrow_rounds`` counts how the
+    engine ran its delivery rounds, of which the fused scan runs none."""
     cfg = AFMConfig(side=10, dim=8, i_max=100, batch=1, e_factor=0.3)
     x = _tiny_data(dim=8, n=512, seed=11)
     key = jax.random.PRNGKey(42)
@@ -535,7 +639,10 @@ def test_zero_fast_path_equals_engine_on_seeded_10x10():
     np.testing.assert_array_equal(np.asarray(fast.state_.w),
                                   np.asarray(slow.state_.w))
     rf, rs = fast.backend.last_report, slow.backend.last_report
+    assert int(rf.narrow_rounds) == 0 < int(rs.narrow_rounds)
     for field in events.EventReport._fields:
+        if field == "narrow_rounds":
+            continue
         np.testing.assert_array_equal(
             np.asarray(getattr(rf, field)), np.asarray(getattr(rs, field)),
             err_msg=f"EventReport.{field}")

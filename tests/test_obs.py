@@ -92,7 +92,7 @@ def _event_engine():
     (functools.partial(_pallas_step, "staged"),
      (obs.AFM_SEARCH, obs.AFM_ADAPT, obs.AFM_CASCADE)),
     (functools.partial(_pallas_step, "fused"), (obs.FUSED_WAVE_KEYS,)),
-    (_event_engine, (obs.EVENTS_POOL,)),
+    (_event_engine, (obs.EVENTS_POOL, obs.EVENTS_DELIVER)),
 ], ids=["staged", "fused", "events"])
 def test_device_scopes_reach_the_hlo_op_name_metadata(build, scopes):
     fn, args = build()

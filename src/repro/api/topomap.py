@@ -196,6 +196,17 @@ class TopoMap:
                 interpret=getattr(self.backend, "interpret", None))
         return self._engine
 
+    def _bmu(self, data, cap: int | None = None):
+        """The engine's BMU search over the fitted map. A Pallas kernel
+        cannot be partitioned, so for the kernel a map spread over several
+        devices (the mesh placement's row bands, the sharded backend's
+        lattice rows) is searched whole on the lowest-id one of them."""
+        w = self.state_.w
+        devs = w.sharding.device_set
+        if self.engine.use_pallas and len(devs) > 1:
+            w = jax.device_put(w, min(devs, key=lambda dev: dev.id))
+        return self.engine.bmu(w, jnp.asarray(data, jnp.float32), cap=cap)
+
     def transform(self, data, *, lattice: bool = False,
                   chunk: int | None = None) -> jnp.ndarray:
         """BMU projection. Returns (B,) flat unit indices, or (B, 2)
@@ -204,8 +215,7 @@ class TopoMap:
         clamped to the bucket ladder so no ``chunk`` value can add a jit
         signature or an oversized dispatch."""
         self._check_fitted()
-        flat, _ = self.engine.bmu(self.state_.w,
-                                  jnp.asarray(data, jnp.float32), cap=chunk)
+        flat, _ = self._bmu(data, cap=chunk)
         if not lattice:
             return flat
         return jnp.stack([flat // self.cfg.side, flat % self.cfg.side], axis=-1)
@@ -224,8 +234,7 @@ class TopoMap:
     def quantization_error(self, data) -> float:
         """Q: mean Euclidean distance of samples to their BMU weight."""
         self._check_fitted()
-        _, q2 = self.engine.bmu(self.state_.w,
-                                jnp.asarray(data, jnp.float32))
+        _, q2 = self._bmu(data)
         return float(jnp.mean(jnp.sqrt(q2)))
 
     def topographic_error(self, data) -> float:

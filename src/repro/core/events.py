@@ -291,6 +291,12 @@ class EventReport(NamedTuple):
     narrow_rounds: jnp.ndarray = 0  # () i32 — delivery rounds served at the
     #                                narrow width (hit share: narrow_rounds /
     #                                (rounds - samples))
+    exchanges: jnp.ndarray = 0     # () i32 — mesh collective steps: lockstep
+    #                                drain iterations plus sample rounds (0 on
+    #                                the single pool)
+    halo_sent: jnp.ndarray = 0     # () i32 — mesh messages enqueued from
+    #                                another shard's outbox (0 on the single
+    #                                pool)
 
     @property
     def events(self):

@@ -16,13 +16,20 @@ Execution model (DESIGN.md §10):
   delivers it; shards working on different cascades in the same iteration
   is the intended semantics, not a race. The loop continues while any
   shard still has a due message (one scalar ``psum`` per iteration).
+- **delivery width** — a round's receiver side runs at the single pool's
+  narrow width (``core.events._NARROW_WIDTH``, via ``_compress``) when the
+  round selects at most that many messages, and at the worst-case width
+  otherwise; both widths add the same operands in the same order
+  (``EventReport.narrow_rounds`` counts the narrow ones, over shards).
 - **halo exchange** — a delivery round's refires (and each sample round's
   threshold crossing) return an *outbox*: boundary-row fire masks plus the
   boundary-row weights. The exchange itself runs unconditionally every
   iteration (collectives cannot sit inside a data-dependent branch); an
   empty outbox exchanges zero masks. Receivers enqueue arriving halo
   messages into their own pool and draw the latency delay from their own
-  stream.
+  stream. ``EventReport.exchanges`` counts the collective steps (drain
+  iterations plus sample rounds) and ``halo_sent`` the messages enqueued
+  from another shard's outbox.
 - **collective search** — a sample round runs on all shards: each probes
   ``e / K`` of its local units, a min-reduce elects the winner, and each
   greedy hop is one more min-reduce over the incumbent's neighbours
@@ -44,6 +51,7 @@ bitwise contract exact rather than merely close).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import NamedTuple
 
@@ -51,6 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import afm as afm_lib
 from repro.core.distributed import _argmin_over_axis
 from repro.core.placement import single as single_mod
@@ -130,6 +139,16 @@ class _Carry(NamedTuple):
     dropped_fault: jnp.ndarray  # () i32 injected losses + dead receivers
     samples_dead: jnp.ndarray  # () i32 samples owned here with a dead GMU
     fault_key: jnp.ndarray     # (2,) u32 per-shard fault stream
+    narrow_rounds: jnp.ndarray  # () i32 local delivery rounds at k_narrow
+    exchanges: jnp.ndarray      # () i32 collective steps (same on every
+    #                              shard): drain iterations + sample rounds
+    halo_sent: jnp.ndarray      # () i32 halo candidates enqueued here
+
+
+#: The carry fields ``enqueue`` writes (``fault_key`` last).
+_POOL_FIELDS = ("msg_t", "msg_gen", "msg_cid", "msg_dst", "msg_dir", "msg_w",
+                "free_head", "free_n", "dropped", "sent", "dropped_fault",
+                "fault_key")
 
 
 class _Outbox(NamedTuple):
@@ -237,6 +256,8 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
     # (≤ 2·side) at zero/constant latency; exponential ties can span the pool
     k_round = m if ecfg.latency == "exponential" else min(4 * length
                                                           + 2 * side, m)
+    # rounds that select at most k_narrow messages run at that width
+    k_narrow = min(k_round, events_lib._NARROW_WIDTH)
     max_waves = single_mod.wave_cap(cfg)
     iter_cap = min(e * (max_waves + 2) + 1, 2 ** 31 - 1)
     e_local = max(1, cfg.e // k_shards)
@@ -350,12 +371,29 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
                          c=jnp.where(fired, 0, cy.c))
         lat_key, lat_sub = split_lat(cy.lat_key)
         cy = cy._replace(lat_key=lat_key)
-        valid = fired[src4] & (dst_local >= 0)
-        tv = t + delays(lat_sub, 4 * length)
-        cy = enqueue(cy, valid, dst_local, dirs4, cy.w[src4], tv,
-                     jnp.asarray(gen, jnp.int32), jnp.asarray(cid, jnp.int32))
         gi = jnp.asarray(gen, jnp.int32)
         ci = jnp.asarray(cid, jnp.int32)
+
+        # the cond closes over exactly the pool fields enqueue mutates (as
+        # the single pool's fire does), so the skip branch is a no-op over
+        # small operands
+        def send(pool):
+            cz = enqueue(cy._replace(**dict(zip(_POOL_FIELDS, pool))),
+                         fired[src4] & (dst_local >= 0), dst_local, dirs4,
+                         cy.w[src4], t + delays(lat_sub, 4 * length), gi, ci)
+            return tuple(getattr(cz, f) for f in _POOL_FIELDS)
+
+        def skip(pool):
+            # enqueue with no valid candidate: only the loss stream moves
+            if loss_on:
+                pool = (*pool[:-1], jax.random.split(pool[-1])[0])
+            return pool
+
+        # most rounds fire nothing on most bands: skip the 4L-wide enqueue
+        with jax.named_scope(obs.EVENTS_POOL):
+            pool = jax.lax.cond(nfired > 0, send, skip,
+                                tuple(getattr(cy, f) for f in _POOL_FIELDS))
+        cy = cy._replace(**dict(zip(_POOL_FIELDS, pool)))
         out = _Outbox(
             up_mask=(fired[:side] & (me > 0)).astype(jnp.int32),
             up_w=cy.w[:side],
@@ -373,6 +411,10 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         stream. Runs unconditionally every round iteration — an idle round
         exchanges zero masks — because collectives cannot live inside a
         data-dependent branch."""
+        with jax.named_scope(obs.MESH_EXCHANGE):
+            return _exchange(cy, out)
+
+    def _exchange(cy: _Carry, out: _Outbox) -> _Carry:
         def shift(x, perm):
             return jax.lax.ppermute(x, AXIS, perm)
         # what I receive "from above" is the shard-above's down-outbox
@@ -397,6 +439,9 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         cidv = jnp.concatenate([jnp.full((side,), a_cid[0]),
                                 jnp.full((side,), b_cid[0])])
         wv = jnp.concatenate([a_w, b_w], axis=0)
+        cy = cy._replace(exchanges=cy.exchanges + 1,
+                         halo_sent=cy.halo_sent
+                         + jnp.sum(valid, dtype=jnp.int32))
         return enqueue(cy, valid, halo_dst, halo_dir, wv, tv, genv, cidv)
 
     def make_round_fns(me, i0, near_g, far_g):
@@ -415,13 +460,16 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
                 return dead_band & (t >= d_lo) & (t < d_hi)
 
         def delivery_round(cy: _Carry, tmin, gmin, cmin, sel):
-            """Deliver one local round: the ≤k_round selected slots are
-            compressed out of the pool, segment-summed per receiver in
-            direction-slot order, and applied as a row scatter — the
-            single-pool delivery math on the local band. Refire gating uses
-            the message generation (``gmin < max_waves``), which is the
-            globally consistent wave depth regardless of how many rounds
-            this shard happened to process."""
+            """Deliver one local round: the selected slots are compressed
+            out of the pool, segment-summed per receiver in direction-slot
+            order, and applied as a row scatter — the single-pool delivery
+            math on the local band. As in the single pool, the receiver side
+            runs at ``k_narrow`` when the round selects at most that many
+            messages and at ``k_round`` otherwise; both widths add the same
+            operands in the same order, so the choice never shows in the
+            result. Refire gating uses the message generation (``gmin <
+            max_waves``), which is the globally consistent wave depth
+            regardless of how many rounds this shard happened to process."""
             cid = cmin
             sched_i = i0 + cid
             l_c = l_c_fn(sched_i, cfg)
@@ -429,57 +477,82 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
             ck, sub = jax.random.split(cy.casc_key[cid])
             bern = (jax.random.uniform(sub, (4, rows, side))
                     < p_i).reshape(4, length)
-            idx = jnp.nonzero(sel, size=k_round, fill_value=m)[0]
-            ok = idx < m
-            ii = jnp.minimum(idx, m - 1)
-            dsts = jnp.where(ok, cy.msg_dst[ii], length)
-            dirs = jnp.where(ok, cy.msg_dir[ii], 0)
-            ws = cy.msg_w[ii]
-            if dead_on:
-                # messages to a dead local unit are consumed but not
-                # delivered (dropped_fault); their slots free normally
-                ok = ok & ~dead_at(tmin)[jnp.minimum(dsts, length - 1)]
-            drive = jnp.where(
-                ok, bern[dirs, jnp.minimum(dsts, length - 1)], False)
-            c = cy.c.at[dsts].add(drive.astype(jnp.int32), mode="drop")
-            n_recv = jnp.zeros((length,), jnp.int32).at[dsts].add(
-                ok.astype(jnp.int32), mode="drop")
-            received = n_recv > 0
-            ridx = jnp.nonzero(received, size=k_round, fill_value=length)[0]
-            pos = jnp.searchsorted(ridx, dsts)
-            acc = jnp.zeros((k_round, d), jnp.float32)
-            for s4 in range(4):                      # direction-slot order
-                acc = acc.at[jnp.where(ok & (dirs == s4), pos,
-                                       k_round)].add(ws, mode="drop")
-            rv = jnp.minimum(ridx, length - 1)
-            nf = n_recv[rv].astype(cy.w.dtype)
-            wr = cy.w[rv]
-            w_rows = wr + l_c * (acc - nf[:, None] * wr)
-            w = cy.w.at[ridx].set(w_rows, mode="drop")
             nsel = jnp.sum(sel, dtype=jnp.int32)
+
+            def receive(width, _=None):
+                """The receiver side at static ``width`` ≥ ``nsel``: returns
+                (w, c, n_recv, ndeliv, free_ring)."""
+                with jax.named_scope(obs.EVENTS_POOL):
+                    idx = events_lib._compress(sel, width)
+                    taken = idx < m
+                    ii = jnp.minimum(idx, m - 1)
+                    dsts = jnp.where(taken, cy.msg_dst[ii], length)
+                    dirs = jnp.where(taken, cy.msg_dir[ii], 0)
+                    ws = cy.msg_w[ii]
+                with jax.named_scope(obs.EVENTS_DELIVER):
+                    ok = taken
+                    if dead_on:
+                        # messages to a dead local unit are consumed but not
+                        # delivered (dropped_fault); their slots free
+                        # normally
+                        ok = ok & ~dead_at(tmin)[jnp.minimum(dsts,
+                                                             length - 1)]
+                    drive = jnp.where(
+                        ok, bern[dirs, jnp.minimum(dsts, length - 1)], False)
+                    c = cy.c.at[dsts].add(drive.astype(jnp.int32),
+                                          mode="drop")
+                    n_recv = jnp.zeros((length,), jnp.int32).at[dsts].add(
+                        ok.astype(jnp.int32), mode="drop")
+                    ridx = events_lib._compress(n_recv > 0, width)
+                    pos = jnp.searchsorted(ridx, dsts)
+                    acc = jnp.zeros((width, d), jnp.float32)
+                    for s4 in range(4):              # direction-slot order
+                        acc = acc.at[jnp.where(ok & (dirs == s4), pos,
+                                               width)].add(ws, mode="drop")
+                    rv = jnp.minimum(ridx, length - 1)
+                    nf = n_recv[rv].astype(cy.w.dtype)
+                    wr = cy.w[rv]
+                    w_rows = wr + l_c * (acc - nf[:, None] * wr)
+                    w = cy.w.at[ridx].set(w_rows, mode="drop")
+                ndeliv = jnp.sum(ok, dtype=jnp.int32) if dead_on else nsel
+                # free the taken slots: push their ids onto the ring tail,
+                # in ascending slot order
+                with jax.named_scope(obs.EVENTS_POOL):
+                    tail = jnp.where(
+                        taken,
+                        (cy.free_head + cy.free_n
+                         + jnp.arange(width, dtype=jnp.int32)) % m, m)
+                    free_ring = cy.free_ring.at[tail].set(idx, mode="drop")
+                return w, c, n_recv, ndeliv, free_ring
+
+            if k_narrow < k_round:
+                narrow = nsel <= k_narrow
+                w, c, n_recv, ndeliv, free_ring = jax.lax.cond(
+                    narrow, functools.partial(receive, k_narrow),
+                    functools.partial(receive, k_round), None)
+                narrow_rounds = cy.narrow_rounds + narrow.astype(jnp.int32)
+            else:
+                w, c, n_recv, ndeliv, free_ring = receive(k_round)
+                narrow_rounds = cy.narrow_rounds
+            received = n_recv > 0
             extra = {}
             if dead_on:
-                ndeliv = jnp.sum(ok, dtype=jnp.int32)
-                extra["dropped_fault"] = (cy.dropped_fault
-                                          + (nsel - ndeliv))
-            else:
-                ndeliv = nsel
-            freed_rank = jnp.cumsum(sel.astype(jnp.int32)) - 1
-            tail = jnp.where(sel,
-                             (cy.free_head + cy.free_n + freed_rank) % m, m)
+                extra["dropped_fault"] = cy.dropped_fault + (nsel - ndeliv)
+            with jax.named_scope(obs.EVENTS_POOL):
+                msg_t = jnp.where(sel, jnp.inf, cy.msg_t)
             cy = cy._replace(
                 w=w, c=c, t=jnp.maximum(cy.t, tmin),
                 clock=jnp.where(received, tmin, cy.clock),
                 nevents=cy.nevents + n_recv,
-                msg_t=jnp.where(sel, jnp.inf, cy.msg_t),
-                free_ring=cy.free_ring.at[tail].set(
-                    jnp.arange(m, dtype=jnp.int32), mode="drop"),
+                msg_t=msg_t,
+                free_ring=free_ring,
                 free_n=cy.free_n + nsel,
                 casc_key=cy.casc_key.at[cid].set(ck),
                 wcount=cy.wcount.at[cid].set(
                     jnp.maximum(cy.wcount[cid], gmin)),
                 deliveries=cy.deliveries + ndeliv,
                 drounds=cy.drounds + 1,
+                narrow_rounds=narrow_rounds,
                 **extra)
             new_fired = (c >= theta) & received
             allowed = new_fired & (gmin < max_waves)
@@ -519,6 +592,25 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
                 (jstar, qstar, jnp.bool_(True), jnp.int32(0)))
             return j, q, steps
 
+        def search_round(w_loc, sample, k_search):
+            """(global unit, its squared distance, greedy hops) of the
+            sample: the local distance pass, then the min-reduce."""
+            if exact:
+                q = jnp.sum((w_loc - sample[None, :]) ** 2, axis=-1)
+                jl = jnp.argmin(q)
+                q2v, gmu_g = _argmin_over_axis(
+                    q[jl][None], (me * length + jl).astype(jnp.int32)[None],
+                    AXIS)
+                return gmu_g[0], q2v[0], jnp.int32(0)
+            kp = jax.random.fold_in(k_search, me)
+            probes = jax.random.randint(kp, (e_local,), 0, length)
+            q = jnp.sum((w_loc[probes] - sample[None, :]) ** 2, axis=-1)
+            kb = jnp.argmin(q)
+            qstar, jstar = _argmin_over_axis(
+                q[kb][None],
+                (me * length + probes[kb]).astype(jnp.int32)[None], AXIS)
+            return greedy(w_loc, sample, jstar[0], qstar[0])
+
         def sample_round(cy: _Carry, sample, step_key, ev):
             """Deliver the next sample collectively: probe-and-reduce (or
             exact) search elects the GMU, the owning shard applies Eq. (3),
@@ -527,24 +619,8 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
             i_now = i0 + ev
             k_search, k_cascade = jax.random.split(step_key)
             p_i = p_fn(i_now, cfg)
-            if exact:
-                q = jnp.sum((cy.w - sample[None, :]) ** 2, axis=-1)
-                jl = jnp.argmin(q)
-                q2v, gmu_g = _argmin_over_axis(
-                    q[jl][None], (me * length + jl).astype(jnp.int32)[None],
-                    AXIS)
-                q2v, gmu_g = q2v[0], gmu_g[0]
-                gsteps = jnp.int32(0)
-            else:
-                kp = jax.random.fold_in(k_search, me)
-                probes = jax.random.randint(kp, (e_local,), 0, length)
-                q = jnp.sum((cy.w[probes] - sample[None, :]) ** 2, axis=-1)
-                kb = jnp.argmin(q)
-                qstar, jstar = _argmin_over_axis(
-                    q[kb][None],
-                    (me * length + probes[kb]).astype(jnp.int32)[None], AXIS)
-                gmu_g, q2v, gsteps = greedy(cy.w, sample,
-                                            jstar[0], qstar[0])
+            with jax.named_scope(obs.MESH_SEARCH):
+                gmu_g, q2v, gsteps = search_round(cy.w, sample, k_search)
             # Eq. (3) at the owner (index `length` is out-of-band -> drop)
             lo = me * length
             mine = (gmu_g >= lo) & (gmu_g < lo + length)
@@ -596,13 +672,15 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
             branch — no collectives inside), then all shards exchange
             halos unconditionally and re-select."""
             def select(cy):
-                return single_mod.pool_min_lex(cy.msg_t, cy.msg_gen,
-                                               cy.msg_cid)
+                with jax.named_scope(obs.EVENTS_POOL):
+                    return single_mod.pool_min_lex(cy.msg_t, cy.msg_gen,
+                                                   cy.msg_cid)
 
             def dcond(st):
                 cy_, (tmin, _g, _c, _s, have), it = st
                 due = have & (tmin <= t_limit)
-                anydue = jax.lax.psum(due.astype(jnp.int32), AXIS) > 0
+                with jax.named_scope(obs.MESH_EXCHANGE):
+                    anydue = jax.lax.psum(due.astype(jnp.int32), AXIS) > 0
                 return anydue & (it < iter_cap)
 
             def dbody(st):
@@ -646,7 +724,9 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
             samples_dead=jnp.int32(0),
             fault_key=(jax.random.fold_in(
                 jax.random.PRNGKey(plan.seed), me)
-                if ecfg.fault_active else z((2,), jnp.uint32)))
+                if ecfg.fault_active else z((2,), jnp.uint32)),
+            narrow_rounds=jnp.int32(0), exchanges=jnp.int32(0),
+            halo_sent=jnp.int32(0))
 
         def sbody(cy, xs):
             sample, key, ev = xs
@@ -674,6 +754,9 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
                 jax.lax.psum(cy.dropped_fault, AXIS),
                 jax.lax.psum(stranded, AXIS),
                 jax.lax.psum(cy.samples_dead, AXIS),
+                jax.lax.psum(cy.narrow_rounds, AXIS),
+                jax.lax.pmax(cy.exchanges, AXIS),
+                jax.lax.psum(cy.halo_sent, AXIS),
                 shard_row)
 
     sharded = P(AXIS)
@@ -684,12 +767,13 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         out_specs=(sharded, sharded, sharded, sharded,
                    repl, repl, repl, repl, repl,
                    repl, repl, repl, repl,
-                   repl, repl, repl, repl, sharded))
+                   repl, repl, repl, repl, repl, repl, repl, sharded))
 
     def go(state, samples, step_keys, lat_key):
         (w, c, clock, nevents, sizes, waves, gmu, q2, greedy,
          rounds, deliveries, dropped, t_end,
-         sent, dropped_fault, stranded, samples_dead, shard_counts) = mapped(
+         sent, dropped_fault, stranded, samples_dead, narrow_rounds,
+         exchanges, halo_sent, shard_counts) = mapped(
             state.w.reshape(side, side, d),
             jnp.asarray(state.c, jnp.int32),
             state.near, state.far, jnp.asarray(state.i, jnp.int32),
@@ -704,7 +788,9 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
             rounds=rounds, samples=jnp.int32(e), deliveries=deliveries,
             dropped=dropped, t_end=t_end, clock=clock, nevents=nevents,
             sent=sent, dropped_fault=dropped_fault, stranded=stranded,
-            samples_dead=samples_dead, shard_counts=shard_counts)
+            samples_dead=samples_dead, shard_counts=shard_counts,
+            narrow_rounds=narrow_rounds, exchanges=exchanges,
+            halo_sent=halo_sent)
         return final, aux, report
 
     return go

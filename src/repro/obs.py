@@ -24,7 +24,11 @@ staged step's three stages), ``fused.wave_keys`` (the fused step's wave-key
 chain and Bernoulli draws), ``events.pool`` (the event engine's message
 pool: selection, enqueue, and the delivery round's take and clear) and
 ``events.deliver`` (the delivery round's receiver side: drive, per-receiver
-segment sums, weight rows).
+segment sums, weight rows), in both placements; and, in the mesh placement
+only, ``mesh.search`` (a sample round's local distance pass and its
+min-reduce over the shards) and ``mesh.exchange`` (the halo ``ppermute``
+pair, the due-flag ``psum`` of each drain iteration, and the enqueue of the
+halo arrivals).
 """
 from __future__ import annotations
 
@@ -44,6 +48,8 @@ AFM_CASCADE = "afm.cascade"
 FUSED_WAVE_KEYS = "fused.wave_keys"
 EVENTS_POOL = "events.pool"
 EVENTS_DELIVER = "events.deliver"
+MESH_SEARCH = "mesh.search"
+MESH_EXCHANGE = "mesh.exchange"
 
 
 def span(name: str, **metadata):

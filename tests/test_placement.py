@@ -13,7 +13,11 @@ Contracts under test:
   ``(seed, shards)`` replays **bitwise** (the per-shard ``fold_in``
   seeding contract documented on ``run_events``), zero-latency training
   quality stays within tolerance of the ``reference`` backend, and the
-  accounting conserves (``samples == E``, ``dropped == 0``).
+  accounting conserves (``samples == E``, ``dropped == 0``);
+- on four forced devices under exponential latency, the mesh matches the
+  plain reference of its semantics (``tests/mesh_ref.py``), teacher-forced,
+  count for count, including its collective steps, halo messages and
+  narrow rounds; its narrow and worst-case delivery widths agree bitwise.
 """
 import importlib.util
 import json
@@ -312,3 +316,179 @@ def test_mesh_fault_accounting_per_shard_and_global():
     assert res["sent"] == (res["deliveries"] + res["dropped_overflow"]
                            + res["dropped_fault"] + res["stranded"]), res
     assert res["dropped_fault"] > 0, res     # the plan genuinely dropped
+
+
+# ------------------ the mesh against its plain reference (forced devices)
+
+_MESH_REF_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import sys
+import jax
+import numpy as np
+from repro import obs
+from repro.api import AFMConfig
+from repro.core import afm, events
+from repro.core.placement import MeshPlacement
+sys.path.insert(0, os.environ["MESH_REF_DIR"])
+import mesh_ref
+
+cfg = AFMConfig(side=8, dim=16, i_max=4096, e_factor=1.0)
+ecfg = events.EventConfig(latency="exponential", delay=1.0)
+E = 96
+key = jax.random.PRNGKey(5)
+k_init, k_data, k_steps, k_lat = jax.random.split(key, 4)
+data = jax.random.uniform(k_data, (2 * E, cfg.dim))
+COUNTS = ("rounds", "samples", "deliveries", "sent", "dropped", "exchanges",
+          "halo_sent", "narrow_rounds")
+
+def runs(ecfg=ecfg):
+    # two consecutive runs, so the second starts at i0 = E with counters set
+    st = afm.init(k_init, cfg, data)
+    out = []
+    for r in range(2):
+        lat = jax.random.fold_in(k_lat, r)
+        steps = jax.random.split(jax.random.fold_in(k_steps, r), E)
+        x = data[r * E:(r + 1) * E]
+        st, aux, rep = events.run_events(
+            st, x, steps, cfg, ecfg, search=afm.search_exact, lat_key=lat,
+            placement="mesh", shards=4)
+        out.append((x, steps, lat, st, aux, rep))
+    return out
+
+def flat(out):
+    # every output but narrow_rounds, which counts the width
+    return [np.asarray(a).tolist() for *_, st, aux, rep in out
+            for a in (st.w, st.c, aux.gmu, aux.q2, aux.cascade_size,
+                      aux.waves) + tuple(rep._replace(narrow_rounds=0))]
+
+narrow = runs()
+again = runs()
+fn = events._compiled_runner(cfg, ecfg, 4, afm.search_exact,
+                             events._default_p, events._default_l_c, False,
+                             MeshPlacement(4))
+hlo = fn.lower(afm.init(k_init, cfg, data), data[:4],
+               jax.random.split(k_steps, 4), k_lat).compile().as_text()
+names = [line for line in hlo.splitlines() if "op_name=" in line]
+scopes = {s: any(f"/{s}/" in line for line in names)
+          for s in (obs.MESH_SEARCH, obs.MESH_EXCHANGE, obs.EVENTS_POOL,
+                    obs.EVENTS_DELIVER)}
+# under constant latency a fire's messages share a round; at a narrow width
+# of 2 the larger rounds take the cond's wide branch
+const = events.EventConfig(latency="constant", delay=1.0)
+events._NARROW_WIDTH = 2
+events._compiled_runner.cache_clear()
+mixed = runs(const)
+events._NARROW_WIDTH = MeshPlacement(4).pool_capacity(cfg, ecfg)
+events._compiled_runner.cache_clear()
+wide = runs()
+const_wide = runs(const)
+
+p = dict(n=cfg.n_units, side=cfg.side, dim=cfg.dim, theta=cfg.theta,
+         l_s=cfg.l_s, c_o=cfg.c_o, c_s=cfg.c_s, c_m=cfg.c_m, c_d=cfg.c_d,
+         i_max=cfg.total_samples, max_waves=8 * cfg.side ** 2)
+rp = mesh_ref.MeshReplay(tuple(sorted(p.items())), shards=4, delay=1.0)
+rp.w = afm.init(k_init, cfg, data).w
+rp.c = np.zeros((cfg.n_units,), np.int64)
+mismatch, prog_counts, ref_counts, best_gap = {}, [], [], 0.0
+for x, steps, lat, st, aux, rep in narrow:
+    got = rp.run(x, steps, lat, gmu=np.asarray(aux.gmu[:, 0]))
+    prog_counts.append({k: int(getattr(rep, k)) for k in COUNTS})
+    ref_counts.append({k: int(got[k]) for k in COUNTS})
+    for k in ("sizes", "waves"):
+        prog = np.asarray(aux.cascade_size if k == "sizes" else aux.waves)
+        mismatch[k] = mismatch.get(k, 0) + int(np.sum(prog != got[k]))
+    best_gap = max(best_gap, float(np.max(got["at_g"] - got["best"])))
+st = narrow[-1][3]
+mismatch["c"] = int(np.sum(np.asarray(st.c) != rp.c))
+w_gap = float(np.max(np.abs(np.asarray(st.w) - np.asarray(rp.w))))
+
+print(json.dumps({
+    "prog_counts": prog_counts, "ref_counts": ref_counts,
+    "mismatch": mismatch, "w_gap": w_gap, "best_gap": best_gap,
+    "w_devices": len(st.w.sharding.device_set),
+    "narrow_equals_wide": flat(narrow) == flat(wide),
+    "wide_narrow_rounds": [int(o[5].narrow_rounds) for o in wide],
+    "mixed_equals_wide": flat(mixed) == flat(const_wide),
+    "mixed_narrow_rounds": [int(o[5].narrow_rounds) for o in mixed],
+    "mixed_delivery_rounds": [int(o[5].rounds - o[5].samples) for o in mixed],
+    "const_wide_narrow_rounds": [int(o[5].narrow_rounds) for o in const_wide],
+    "repeat_bitwise": flat(narrow) == flat(again),
+    "scopes": scopes,
+}))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh_vs_reference():
+    """One 4-device subprocess: the mesh at side 8, D = 16, exponential
+    latency, two consecutive runs of 96 samples, replayed by the plain
+    reference (``tests/mesh_ref.py``) teacher-forced by the program's units;
+    the same runs again, and with every delivery round at the worst-case
+    width; and the runner's compiled HLO."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(_HERE, "..", "src"),
+               MESH_REF_DIR=_HERE)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _MESH_REF_SCRIPT],
+                         capture_output=True, text=True, env=env,
+                         timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_mesh_matches_plain_reference_teacher_forced(mesh_vs_reference):
+    """Every call's rounds, samples, deliveries, sent and dropped messages,
+    each cascade's size and waves, and the final counters equal the plain
+    reference's exactly. The weights agree to 1e-6: both apply the same
+    float32 operations in the same order, but in separately compiled
+    programs, where XLA may fuse a multiply and an add differently (they
+    read 0 apart here)."""
+    res = mesh_vs_reference
+    assert res["prog_counts"] == res["ref_counts"], res
+    assert res["mismatch"] == {"sizes": 0, "waves": 0, "c": 0}, res
+    assert res["w_gap"] < 1e-6, res
+    # the reference's own distances agree with the units the program chose
+    assert res["best_gap"] <= 1e-5, res
+    assert all(c["deliveries"] > 0 and c["halo_sent"] > 0
+               for c in res["prog_counts"]), res
+    assert res["w_devices"] == 4
+
+
+def test_mesh_counters_agree_with_reference(mesh_vs_reference):
+    """``exchanges`` (drain iterations plus sample rounds), ``halo_sent``
+    and ``narrow_rounds`` count what the reference counts; under
+    exponential latency every delivery round runs narrow."""
+    for prog, ref in zip(mesh_vs_reference["prog_counts"],
+                         mesh_vs_reference["ref_counts"]):
+        for k in ("exchanges", "halo_sent", "narrow_rounds"):
+            assert prog[k] == ref[k], (k, prog, ref)
+        assert prog["exchanges"] >= prog["samples"]
+        assert prog["narrow_rounds"] == prog["rounds"] - prog["samples"]
+
+
+def test_mesh_narrow_and_wide_rounds_agree_bitwise(mesh_vs_reference):
+    """The narrow and the worst-case delivery width give bit for bit the
+    same weights, counters, aux and report (``narrow_rounds`` aside, which
+    counts the width): under exponential latency, where every round is
+    narrow, and under constant latency at a narrow width of 2, where the
+    round's ``lax.cond`` takes both branches."""
+    res = mesh_vs_reference
+    assert res["narrow_equals_wide"]
+    assert res["wide_narrow_rounds"] == [0, 0]
+    assert res["mixed_equals_wide"]
+    assert res["const_wide_narrow_rounds"] == [0, 0]
+    for narrow, rounds in zip(res["mixed_narrow_rounds"],
+                              res["mixed_delivery_rounds"]):
+        assert 0 < narrow < rounds, res
+
+
+def test_mesh_exponential_replays_bitwise(mesh_vs_reference):
+    """Same seeds and shard count reproduce bitwise under exponential
+    latency, with the narrow round in the loop."""
+    assert mesh_vs_reference["repeat_bitwise"]
+
+
+def test_mesh_scopes_reach_the_hlo_op_name_metadata(mesh_vs_reference):
+    assert all(mesh_vs_reference["scopes"].values()), \
+        mesh_vs_reference["scopes"]

@@ -30,14 +30,14 @@ FUSED_MAX_SIDE = 56
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
         try:
-            topo = topologies.get_topology_desc(platform="tpu",
+            desc = topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
         except Exception as e:  # noqa: BLE001 — any failure means no TPU lib
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -45,10 +45,15 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield desc
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _shape(sharding, shape, dtype=jnp.float32):
@@ -101,3 +106,37 @@ def test_bmu_engine_bucket_compiles_for_v5e(one_chip):
                    _shape(one_chip, (SIDE * SIDE, DIM)),
                    _shape(one_chip, (engine.buckets[2], DIM)))
 
+
+
+def test_mesh_event_runner_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The event engine's mesh placement over the host's four chips (row
+    bands of a side-40 map at D = 784, exponential latency): the runner
+    compiles, with the halo exchange as collective-permutes and the
+    due-flag and min-reduce collectives beside it."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+    from repro.core import afm, events
+    from repro.core.placement import MeshPlacement
+    from repro.core.placement import mesh as mesh_mod
+
+    mesh = Mesh(np.array(topo.devices), (mesh_mod.AXIS,),
+                axis_types=(AxisType.Auto,))
+    # the runner takes its mesh from the placement's cache, which would
+    # otherwise build it over the visible (CPU) devices
+    monkeypatch.setitem(mesh_mod._MESHES._meshes, 4, mesh)
+    cfg = AFMConfig(side=SIDE, dim=DIM, batch=1, i_max=40 * SIDE * SIDE)
+    ecfg = events.EventConfig(latency="exponential", delay=1.0)
+    e = 8
+    placement = MeshPlacement(4)
+    runner = placement.build_runner(cfg, ecfg, e, afm.search_exact,
+                                    events._default_p, events._default_l_c)
+    repl = NamedSharding(mesh, PartitionSpec())
+    state = jax.tree.map(
+        lambda x: _shape(repl, x.shape, x.dtype),
+        jax.eval_shape(lambda k: afm.init(k, cfg), jax.random.PRNGKey(0)))
+    text = jax.jit(runner).lower(
+        state, _shape(repl, (e, DIM)), _shape(repl, (e, 2), jnp.uint32),
+        _shape(repl, (2,), jnp.uint32)).compile().as_text()
+    assert "collective-permute" in text
+    assert "all-reduce" in text

@@ -108,6 +108,56 @@ def _compress(mask, width: int):
     return jnp.sum(rank[None, :] <= jnp.arange(width, dtype=jnp.int32)[:, None],
                    axis=1, dtype=jnp.int32)
 
+
+def _fire_units(n_local: int) -> int:
+    """Most fired units an enqueue takes at the narrow width (each sends at
+    most 4 candidates), or 0 where ``n_local`` units' ``4·n_local``
+    candidates are no wider than that: the enqueue then has one width."""
+    units = _NARROW_WIDTH // 4
+    return units if _NARROW_WIDTH < 4 * n_local else 0
+
+
+def _fire_candidates(fired, units: int):
+    """The candidates of the first ``units`` fired units at a static width
+    ``4 · units``: ids ``4·unit + direction`` in (unit, direction) order,
+    which is the order of the wide enqueue's ``(units, 4)`` reshape, so the
+    r-th valid candidate is the same at both widths. Returns (ids clipped
+    into range, real) — fill entries past the fired units are not real."""
+    unit = _compress(fired, units)
+    ids = (unit[:, None] * 4 + jnp.arange(4, dtype=jnp.int32)).reshape(-1)
+    size = 4 * fired.shape[0]
+    return jnp.minimum(ids, size - 1), ids < size
+
+
+def _enqueue_by_count(count, most: int, skip, enqueue, pool):
+    """Run an enqueue at the width its ``count`` (of fired units, or of
+    valid arrivals) asks for: ``skip(pool)`` when the count is 0,
+    ``enqueue(True, pool)`` when it is at most ``most``, and
+    ``enqueue(False, pool)`` when it is more (every nonzero count where
+    ``most`` is 0). Returns (pool, whether the narrow width ran)."""
+    wide = functools.partial(enqueue, False)
+    if not most:
+        return jax.lax.cond(count > 0, wide, skip, pool), jnp.bool_(False)
+    return (jax.lax.switch(jnp.minimum(count, 1) + (count > most),
+                           (skip, functools.partial(enqueue, True), wide),
+                           pool),
+            (count > 0) & (count <= most))
+
+
+def _allocate(valid, free_ring, free_head, free_n):
+    """Pool slots off the free ring for the candidates ``valid`` marks, at
+    its static width: the r-th valid candidate takes the r-th free slot and
+    candidates past the free count get the out-of-range slot (their
+    ``mode="drop"`` scatters write nothing). Returns (slot, allocated,
+    overflow)."""
+    m = free_ring.shape[0]
+    rank = jnp.cumsum(valid, dtype=jnp.int32) - 1
+    can = valid & (rank < free_n)
+    slot = jnp.where(can, free_ring[(free_head + rank) % m], m)
+    nalloc = jnp.sum(can, dtype=jnp.int32)
+    return slot, nalloc, jnp.sum(valid, dtype=jnp.int32) - nalloc
+
+
 #: Direction codes, from the *receiver*'s perspective, matching the slot
 #: order of ``core.cascade._shift4``: 0 = from row+1 (below), 1 = from row-1
 #: (above), 2 = from col+1 (right), 3 = from col-1 (left). A sender's 4
@@ -260,6 +310,8 @@ class EventState(NamedTuple):
     samples_dead: jnp.ndarray  # () i32 — samples routed to a dead GMU
     fault_key: jnp.ndarray     # (2,) u32 — the plan's own PRNG stream
     narrow_rounds: jnp.ndarray  # () i32 — delivery rounds run at k_narrow
+    narrow_fires: jnp.ndarray   # () i32 — fire() enqueues run narrow
+    wide_fires: jnp.ndarray     # () i32 — fire() enqueues run at 4N
 
 
 class EventReport(NamedTuple):
@@ -297,6 +349,12 @@ class EventReport(NamedTuple):
     halo_sent: jnp.ndarray = 0     # () i32 — mesh messages enqueued from
     #                                another shard's outbox (0 on the single
     #                                pool)
+    narrow_fires: jnp.ndarray = 0  # () i32 — fire() enqueues run at the
+    #                                narrow width (hit share: narrow_fires /
+    #                                (narrow_fires + wide_fires)); summed
+    #                                over shards
+    wide_fires: jnp.ndarray = 0    # () i32 — fire() enqueues run over every
+    #                                candidate (4N, or 4L on a mesh shard)
 
     @property
     def events(self):
@@ -352,7 +410,8 @@ def init_events(state: AFMState, cfg: AFMConfig, ecfg: EventConfig,
         samples_dead=jnp.int32(0),
         fault_key=(jax.random.PRNGKey(ecfg.plan.seed)
                    if ecfg.fault_active else z((2,), jnp.uint32)),
-        narrow_rounds=jnp.int32(0),
+        narrow_rounds=jnp.int32(0), narrow_fires=jnp.int32(0),
+        wide_fires=jnp.int32(0),
     )
 
 
@@ -369,7 +428,7 @@ def _default_l_c(i, cfg: AFMConfig):
 def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
                     search: Callable, p_fn: Callable, l_c_fn: Callable,
                     i0, far, near, placement=None):
-    """Build (sample_round, delivery_round, pool_min) as closures.
+    """Build (sample_round, delivery_round, pool_min, fire) as closures.
 
     ``i0`` is the run's starting sample count: cascade ``cid`` uses the
     schedules evaluated at ``i0 + cid`` throughout its lifetime — exactly
@@ -410,12 +469,22 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         with jax.named_scope(obs.EVENTS_POOL):
             return selector(es.msg_t, es.msg_key, es.msg_gen, es.msg_cid)
 
+    fire_units = _fire_units(n)
+
     def fire(es: EventState, fired, cid, t, gen) -> EventState:
         """Broadcast-after-theta: ``fired`` units reset their counters and
         enqueue weight messages to their near neighbours (payload = the
         sender's current w), timestamped by the latency model. Pool slots
         come off the free ring: the r-th valid candidate takes the r-th
         free slot, candidates past the free count are dropped (counted).
+
+        The enqueue's static width follows the fire's own count: a fire of
+        at most ``fire_units`` units (every fire of an exponential-latency
+        run) enqueues their ≤ 4·``fire_units`` candidates, a larger one all
+        4N, and a fire of none skips the enqueue. The narrow candidates are
+        the wide ones in the same order, with their latency and loss draws
+        taken from the same (4N,) tensors, so the width never shows in the
+        pool (``narrow_fires`` / ``wide_fires`` count it).
 
         Faults: dead units neither fire nor count as firing incidents (a
         unit whose counter crossed ``theta`` while dead fires on rejoin at
@@ -438,37 +507,45 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         gen_u = jnp.asarray(gen, jnp.int32)
         cid_u = jnp.asarray(cid, jnp.int32)
 
-        # the cond closes over exactly the pool fields enqueue mutates, so
-        # the skip branch is a no-op over small operands (not the full
+        # the branches close over exactly the pool fields enqueue mutates,
+        # so the skip branch is a no-op over small operands (not the full
         # EventState — E-sized aux arrays never enter the conditional)
         pool = (es.msg_t, es.msg_key, es.msg_gen, es.msg_cid, es.msg_dst,
                 es.msg_dir, es.msg_w, es.free_head, es.free_n, es.dropped,
                 es.sent, es.dropped_fault, es.fault_key)
 
-        def enqueue(pool):
+        def enqueue(narrow: bool, pool):
             (msg_t, msg_key, msg_gen, msg_cid, msg_dst, msg_dir, msg_w,
              free_head, free_n, drop0, sent0, dfault0, fkey) = pool
-            # candidate messages: (N, 4) in near-table order (up, down,
-            # left, right) == receiver direction codes (below, above,
-            # right, left)
-            valid = (fired[:, None] & (near >= 0)).reshape(-1)       # (4N,)
-            sent0 = sent0 + jnp.sum(valid, dtype=jnp.int32)
+            # PRNG draws keep their (4N,) shapes at either width
             if loss_on:
                 fkey, sub = jax.random.split(fkey)
                 keep = jax.random.uniform(sub, (4 * n,)) >= plan.p_loss
-                dfault0 = dfault0 + jnp.sum(valid & ~keep, dtype=jnp.int32)
-                valid = valid & keep
             if ecfg.latency == "exponential":
                 delay = jax.random.exponential(lat_sub, (4 * n,)) * ecfg.delay
             elif ecfg.latency == "constant":
                 delay = jnp.full((4 * n,), ecfg.delay, jnp.float32)
             else:
                 delay = jnp.zeros((4 * n,), jnp.float32)
-            rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
-            can = valid & (rank < free_n)
-            slot = jnp.where(can, es.free_ring[(free_head + rank) % m], m)
-            nalloc = jnp.sum(can, dtype=jnp.int32)
-            dropped = jnp.sum(valid, dtype=jnp.int32) - nalloc
+            if narrow:
+                ids, real = _fire_candidates(fired, fire_units)
+                dst, dirs, src = dst4[ids], dirs4[ids], src4[ids]
+                valid = real & (dst >= 0)
+                delay = delay[ids]
+                if loss_on:
+                    keep = keep[ids]
+            else:
+                # candidate messages: (N, 4) in near-table order (up, down,
+                # left, right) == receiver direction codes (below, above,
+                # right, left)
+                dst, dirs, src = dst4, dirs4, src4
+                valid = (fired[:, None] & (near >= 0)).reshape(-1)   # (4N,)
+            sent0 = sent0 + jnp.sum(valid, dtype=jnp.int32)
+            if loss_on:
+                dfault0 = dfault0 + jnp.sum(valid & ~keep, dtype=jnp.int32)
+                valid = valid & keep
+            slot, nalloc, dropped = _allocate(valid, es.free_ring, free_head,
+                                              free_n)
             if scale is not None:
                 packed = (gen_u.astype(jnp.uint32) * jnp.uint32(scale)
                           + cid_u.astype(jnp.uint32))
@@ -478,23 +555,27 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
                 msg_cid = msg_cid.at[slot].set(cid_u, mode="drop")
             return (msg_t.at[slot].set(t + delay, mode="drop"),
                     msg_key, msg_gen, msg_cid,
-                    msg_dst.at[slot].set(dst4, mode="drop"),
-                    msg_dir.at[slot].set(dirs4, mode="drop"),
-                    msg_w.at[slot].set(es.w[src4], mode="drop"),
+                    msg_dst.at[slot].set(dst, mode="drop"),
+                    msg_dir.at[slot].set(dirs, mode="drop"),
+                    msg_w.at[slot].set(es.w[src], mode="drop"),
                     (free_head + nalloc) % m, free_n - nalloc,
                     drop0 + dropped, sent0, dfault0, fkey)
 
         # most rounds fire nothing: skip the pool scatters entirely then
         with jax.named_scope(obs.EVENTS_POOL):
-            (msg_t, msg_key, msg_gen, msg_cid, msg_dst, msg_dir, msg_w,
-             free_head, free_n, dropped, sent, dfault,
-             fault_key) = jax.lax.cond(nfired > 0, enqueue, lambda p: p, pool)
+            pool, narrow = _enqueue_by_count(nfired, fire_units, lambda p: p,
+                                             enqueue, pool)
+        (msg_t, msg_key, msg_gen, msg_cid, msg_dst, msg_dir, msg_w,
+         free_head, free_n, dropped, sent, dfault, fault_key) = pool
         return es._replace(
             c=c, sizes=sizes, lat_key=lat_key,
             msg_t=msg_t, msg_key=msg_key, msg_gen=msg_gen, msg_cid=msg_cid,
             msg_dst=msg_dst, msg_dir=msg_dir, msg_w=msg_w,
             free_head=free_head, free_n=free_n, dropped=dropped,
-            sent=sent, dropped_fault=dfault, fault_key=fault_key)
+            sent=sent, dropped_fault=dfault, fault_key=fault_key,
+            narrow_fires=es.narrow_fires + narrow.astype(jnp.int32),
+            wide_fires=es.wide_fires
+            + ((nfired > 0) & ~narrow).astype(jnp.int32))
 
     def sample_round(es: EventState, sample, step_key) -> EventState:
         """Deliver the next sample: search routes it, the GMU adapts
@@ -667,7 +748,7 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         allowed = new_fired & (k_wave < max_waves)
         return fire(es, allowed, cid, tmin, gmin + 1)
 
-    return sample_round, delivery_round, pool_min
+    return sample_round, delivery_round, pool_min, fire
 
 
 def _finish(es: EventState, far, near):
@@ -688,7 +769,8 @@ def _finish(es: EventState, far, near):
         samples_dead=es.samples_dead,
         shard_counts=jnp.stack([es.sent, es.deliveries, es.dropped,
                                 es.dropped_fault, stranded])[None, :],
-        narrow_rounds=es.narrow_rounds)
+        narrow_rounds=es.narrow_rounds, narrow_fires=es.narrow_fires,
+        wide_fires=es.wide_fires)
     return final, aux, report
 
 
@@ -859,7 +941,7 @@ def _make_engine(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
 
     def go(state: AFMState, samples, step_keys, lat_key):
         es0 = init_events(state, cfg, ecfg, e, lat_key)
-        sample_round, delivery_round, pool_min = _make_round_fns(
+        sample_round, delivery_round, pool_min, _ = _make_round_fns(
             cfg, ecfg, e, search, p_fn, l_c_fn, i0=es0.i,
             far=state.far, near=state.near, placement=placement)
 
@@ -903,7 +985,7 @@ def _make_budgeted(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
 
     def go(state: AFMState, samples, step_keys, lat_key):
         es0 = init_events(state, cfg, ecfg, e, lat_key)
-        sample_round, delivery_round, pool_min = _make_round_fns(
+        sample_round, delivery_round, pool_min, _ = _make_round_fns(
             cfg, ecfg, e, search, p_fn, l_c_fn, i0=es0.i,
             far=state.far, near=state.near, placement=placement)
 
@@ -1018,7 +1100,7 @@ def run_events(state: AFMState, samples: jnp.ndarray, step_keys: jnp.ndarray,
                 sent=zero, dropped_fault=zero, stranded=zero,
                 samples_dead=zero,
                 shard_counts=jnp.zeros((1, 5), jnp.int32),
-                narrow_rounds=zero)
+                narrow_rounds=zero, narrow_fires=zero, wide_fires=zero)
     if lat_key is None:
         lat_key = jax.random.PRNGKey(lat_seed)
     pl = placement_base.resolve_placement(placement, shards=shards)

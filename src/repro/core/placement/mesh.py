@@ -21,6 +21,14 @@ Execution model (DESIGN.md §10):
   round selects at most that many messages, and at the worst-case width
   otherwise; both widths add the same operands in the same order
   (``EventReport.narrow_rounds`` counts the narrow ones, over shards).
+- **enqueue width** — ``fire()``'s enqueue on a band runs at the same
+  narrow width when at most ``_NARROW_WIDTH // 4`` of the band's units
+  fire, over all 4L candidates when more do, and not at all when none
+  does; a halo's arrivals enqueue at the narrow width when at most that
+  many are valid, and not at all when none is. Slots come from
+  ``core.events._allocate`` at either width, so the width never shows in
+  the pool (``EventReport.narrow_fires`` and ``wide_fires`` count the fire
+  enqueues, over shards).
 - **halo exchange** — a delivery round's refires (and each sample round's
   threshold crossing) return an *outbox*: boundary-row fire masks plus the
   boundary-row weights. The exchange itself runs unconditionally every
@@ -140,12 +148,15 @@ class _Carry(NamedTuple):
     samples_dead: jnp.ndarray  # () i32 samples owned here with a dead GMU
     fault_key: jnp.ndarray     # (2,) u32 per-shard fault stream
     narrow_rounds: jnp.ndarray  # () i32 local delivery rounds at k_narrow
+    narrow_fires: jnp.ndarray   # () i32 local fire() enqueues run narrow
+    wide_fires: jnp.ndarray     # () i32 local fire() enqueues run at 4L
     exchanges: jnp.ndarray      # () i32 collective steps (same on every
     #                              shard): drain iterations + sample rounds
     halo_sent: jnp.ndarray      # () i32 halo candidates enqueued here
 
 
-#: The carry fields ``enqueue`` writes (``fault_key`` last).
+#: The carry fields an enqueue writes (``fault_key``, which its loss draw
+#: advances, last).
 _POOL_FIELDS = ("msg_t", "msg_gen", "msg_cid", "msg_dst", "msg_dir", "msg_w",
                 "free_head", "free_n", "dropped", "sent", "dropped_fault",
                 "fault_key")
@@ -256,8 +267,13 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
     # (≤ 2·side) at zero/constant latency; exponential ties can span the pool
     k_round = m if ecfg.latency == "exponential" else min(4 * length
                                                           + 2 * side, m)
-    # rounds that select at most k_narrow messages run at that width
+    # rounds that select at most k_narrow messages run at that width; so do
+    # fires of at most fire_units units and halos of at most halo_width
+    # arrivals
     k_narrow = min(k_round, events_lib._NARROW_WIDTH)
+    fire_units = events_lib._fire_units(length)
+    halo_width = (events_lib._NARROW_WIDTH
+                  if events_lib._NARROW_WIDTH < 2 * side else 0)
     max_waves = single_mod.wave_cap(cfg)
     iter_cap = min(e * (max_waves + 2) + 1, 2 ** 31 - 1)
     e_local = max(1, cfg.e // k_shards)
@@ -331,26 +347,31 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         return _Outbox(zi, zw, zi, zw, jnp.zeros((1,), jnp.float32),
                        jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32))
 
-    def enqueue(cy: _Carry, valid, dstv, dirv, wv, tv, genv, cidv) -> _Carry:
-        """Allocate pool slots off the free ring for the valid candidates:
-        the r-th valid candidate takes the r-th free slot; candidates past
-        the free count are dropped and counted. Fault accounting is
-        owner-side: ``sent`` counts every valid candidate before the loss
-        draw, so sent == delivered + overflow + fault + stranded per shard."""
+    def lose(cy: _Carry, count: int):
+        """The loss draw over a site's ``count`` candidates, at the site's
+        full width whatever width its enqueue runs at: (carry, keep), with
+        ``keep`` None and the fault stream untouched without ``p_loss``."""
+        if not loss_on:
+            return cy, None
+        fkey, sub = jax.random.split(cy.fault_key)
+        return (cy._replace(fault_key=fkey),
+                jax.random.uniform(sub, (count,)) >= plan.p_loss)
+
+    def enqueue(cy: _Carry, valid, keep, dstv, dirv, wv, tv, genv,
+                cidv) -> _Carry:
+        """Allocate pool slots off the free ring for the valid candidates
+        (``events._allocate``): the r-th valid candidate takes the r-th
+        free slot; candidates past the free count are dropped and counted.
+        Fault accounting is owner-side: ``sent`` counts every valid
+        candidate before the loss draw ``keep``, so sent == delivered +
+        overflow + fault + stranded per shard."""
         cy = cy._replace(sent=cy.sent + jnp.sum(valid, dtype=jnp.int32))
-        if loss_on:
-            fkey, sub = jax.random.split(cy.fault_key)
-            keep = jax.random.uniform(sub, valid.shape) >= plan.p_loss
-            cy = cy._replace(
-                fault_key=fkey,
-                dropped_fault=cy.dropped_fault
-                + jnp.sum(valid & ~keep, dtype=jnp.int32))
+        if keep is not None:
+            cy = cy._replace(dropped_fault=cy.dropped_fault
+                             + jnp.sum(valid & ~keep, dtype=jnp.int32))
             valid = valid & keep
-        rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
-        can = valid & (rank < cy.free_n)
-        slot = jnp.where(can, cy.free_ring[(cy.free_head + rank) % m], m)
-        nalloc = jnp.sum(can, dtype=jnp.int32)
-        drop = jnp.sum(valid, dtype=jnp.int32) - nalloc
+        slot, nalloc, drop = events_lib._allocate(valid, cy.free_ring,
+                                                  cy.free_head, cy.free_n)
         return cy._replace(
             msg_t=cy.msg_t.at[slot].set(tv, mode="drop"),
             msg_gen=cy.msg_gen.at[slot].set(genv, mode="drop"),
@@ -362,10 +383,19 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
             free_n=cy.free_n - nalloc,
             dropped=cy.dropped + drop)
 
+    def pool_of(cy: _Carry):
+        return tuple(getattr(cy, f) for f in _POOL_FIELDS)
+
+    def with_pool(cy: _Carry, pool) -> _Carry:
+        return cy._replace(**dict(zip(_POOL_FIELDS, pool)))
+
     def fire(cy: _Carry, me, fired, cid, t, gen):
         """Broadcast-after-theta on the local band: reset counters, enqueue
         the in-shard neighbour messages, and emit the boundary-row firings
-        as this round's outbox (delivered by the caller's exchange)."""
+        as this round's outbox (delivered by the caller's exchange). As on
+        the single pool, the enqueue runs at the narrow width when at most
+        ``fire_units`` units fire, over all 4L candidates when more do, and
+        not at all when none does."""
         nfired = jnp.sum(fired, dtype=jnp.int32)
         cy = cy._replace(sizes=cy.sizes.at[cid].add(nfired),
                          c=jnp.where(fired, 0, cy.c))
@@ -374,14 +404,22 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         gi = jnp.asarray(gen, jnp.int32)
         ci = jnp.asarray(cid, jnp.int32)
 
-        # the cond closes over exactly the pool fields enqueue mutates (as
-        # the single pool's fire does), so the skip branch is a no-op over
-        # small operands
-        def send(pool):
-            cz = enqueue(cy._replace(**dict(zip(_POOL_FIELDS, pool))),
-                         fired[src4] & (dst_local >= 0), dst_local, dirs4,
-                         cy.w[src4], t + delays(lat_sub, 4 * length), gi, ci)
-            return tuple(getattr(cz, f) for f in _POOL_FIELDS)
+        # the branches close over exactly the pool fields enqueue mutates
+        # (as the single pool's fire does), so the skip branch is a no-op
+        # over small operands
+        def send(narrow: bool, pool):
+            cz, keep = lose(with_pool(cy, pool), 4 * length)
+            tv = t + delays(lat_sub, 4 * length)
+            if narrow:
+                ids, real = events_lib._fire_candidates(fired, fire_units)
+                dst = dst_local[ids]
+                cz = enqueue(cz, real & (dst >= 0),
+                             None if keep is None else keep[ids], dst,
+                             dirs4[ids], cy.w[src4[ids]], tv[ids], gi, ci)
+            else:
+                cz = enqueue(cz, fired[src4] & (dst_local >= 0), keep,
+                             dst_local, dirs4, cy.w[src4], tv, gi, ci)
+            return pool_of(cz)
 
         def skip(pool):
             # enqueue with no valid candidate: only the loss stream moves
@@ -389,11 +427,14 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
                 pool = (*pool[:-1], jax.random.split(pool[-1])[0])
             return pool
 
-        # most rounds fire nothing on most bands: skip the 4L-wide enqueue
+        # most rounds fire nothing on most bands: skip the enqueue
         with jax.named_scope(obs.EVENTS_POOL):
-            pool = jax.lax.cond(nfired > 0, send, skip,
-                                tuple(getattr(cy, f) for f in _POOL_FIELDS))
-        cy = cy._replace(**dict(zip(_POOL_FIELDS, pool)))
+            pool, narrow = events_lib._enqueue_by_count(
+                nfired, fire_units, skip, send, pool_of(cy))
+        cy = with_pool(cy, pool)._replace(
+            narrow_fires=cy.narrow_fires + narrow.astype(jnp.int32),
+            wide_fires=cy.wide_fires
+            + ((nfired > 0) & ~narrow).astype(jnp.int32))
         out = _Outbox(
             up_mask=(fired[:side] & (me > 0)).astype(jnp.int32),
             up_w=cy.w[:side],
@@ -439,10 +480,32 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         cidv = jnp.concatenate([jnp.full((side,), a_cid[0]),
                                 jnp.full((side,), b_cid[0])])
         wv = jnp.concatenate([a_w, b_w], axis=0)
+        nvalid = jnp.sum(valid, dtype=jnp.int32)
         cy = cy._replace(exchanges=cy.exchanges + 1,
-                         halo_sent=cy.halo_sent
-                         + jnp.sum(valid, dtype=jnp.int32))
-        return enqueue(cy, valid, halo_dst, halo_dir, wv, tv, genv, cidv)
+                         halo_sent=cy.halo_sent + nvalid)
+        cy, keep = lose(cy, 2 * side)
+
+        def arrive(narrow: bool, pool):
+            cz = with_pool(cy, pool)
+            if narrow:
+                # the valid arrivals in ascending order, as the wide
+                # enqueue ranks them
+                idx = events_lib._compress(valid, halo_width)
+                ii = jnp.minimum(idx, 2 * side - 1)
+                cz = enqueue(cz, idx < 2 * side,
+                             None if keep is None else keep[ii],
+                             halo_dst[ii], halo_dir[ii], wv[ii], tv[ii],
+                             genv[ii], cidv[ii])
+            else:
+                cz = enqueue(cz, valid, keep, halo_dst, halo_dir, wv, tv,
+                             genv, cidv)
+            return pool_of(cz)
+
+        # most iterations carry no arrival, and a burst seldom more than
+        # halo_width: skip the enqueue, or run it at that width
+        pool, _ = events_lib._enqueue_by_count(
+            nvalid, halo_width, lambda p: p, arrive, pool_of(cy))
+        return with_pool(cy, pool)
 
     def make_round_fns(me, i0, near_g, far_g):
         """Per-shard round handlers (closures over the shard index, the
@@ -726,7 +789,8 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
                 jax.random.PRNGKey(plan.seed), me)
                 if ecfg.fault_active else z((2,), jnp.uint32)),
             narrow_rounds=jnp.int32(0), exchanges=jnp.int32(0),
-            halo_sent=jnp.int32(0))
+            halo_sent=jnp.int32(0), narrow_fires=jnp.int32(0),
+            wide_fires=jnp.int32(0))
 
         def sbody(cy, xs):
             sample, key, ev = xs
@@ -757,6 +821,8 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
                 jax.lax.psum(cy.narrow_rounds, AXIS),
                 jax.lax.pmax(cy.exchanges, AXIS),
                 jax.lax.psum(cy.halo_sent, AXIS),
+                jax.lax.psum(cy.narrow_fires, AXIS),
+                jax.lax.psum(cy.wide_fires, AXIS),
                 shard_row)
 
     sharded = P(AXIS)
@@ -767,13 +833,15 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         out_specs=(sharded, sharded, sharded, sharded,
                    repl, repl, repl, repl, repl,
                    repl, repl, repl, repl,
-                   repl, repl, repl, repl, repl, repl, repl, sharded))
+                   repl, repl, repl, repl, repl, repl, repl, repl, repl,
+                   sharded))
 
     def go(state, samples, step_keys, lat_key):
         (w, c, clock, nevents, sizes, waves, gmu, q2, greedy,
          rounds, deliveries, dropped, t_end,
          sent, dropped_fault, stranded, samples_dead, narrow_rounds,
-         exchanges, halo_sent, shard_counts) = mapped(
+         exchanges, halo_sent, narrow_fires, wide_fires,
+         shard_counts) = mapped(
             state.w.reshape(side, side, d),
             jnp.asarray(state.c, jnp.int32),
             state.near, state.far, jnp.asarray(state.i, jnp.int32),
@@ -790,7 +858,8 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
             sent=sent, dropped_fault=dropped_fault, stranded=stranded,
             samples_dead=samples_dead, shard_counts=shard_counts,
             narrow_rounds=narrow_rounds, exchanges=exchanges,
-            halo_sent=halo_sent)
+            halo_sent=halo_sent, narrow_fires=narrow_fires,
+            wide_fires=wide_fires)
         return final, aux, report
 
     return go

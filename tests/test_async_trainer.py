@@ -35,6 +35,7 @@ from repro.analysis.runtime import TraceGuard
 from repro.api import AFMConfig, TopoMap, available_backends, get_backend
 from repro.core import afm, events, sandpile
 from repro.core import search as search_lib
+from repro.core.placement import SinglePool
 from repro.data import make_dataset
 from repro.faults import FaultPlan
 from repro.launch.stream_train import run_stream
@@ -517,6 +518,167 @@ def test_narrow_rounds_count_the_delivery_rounds():
     assert int(empty.narrow_rounds) == 0
 
 
+#: A side-8 map (4N = 256 fire candidates, four times the narrow width)
+#: under each pool mode an enqueue can meet.
+_FIRE_CFG = AFMConfig(side=8, dim=4, theta=3, i_max=96, e_factor=0.5)
+_FIRE_EXP = dict(latency="exponential", delay=2.5)
+_FIRE_CASES = {
+    "packed": (_FIRE_CFG, _FIRE_EXP),
+    "lex": (dataclasses.replace(_FIRE_CFG, max_waves=2 ** 30), _FIRE_EXP),
+    "constant": (_FIRE_CFG, dict(latency="constant", delay=1.5)),
+    "overflow": (_FIRE_CFG, dict(_FIRE_EXP, capacity=48)),
+    "loss": (_FIRE_CFG, dict(_FIRE_EXP,
+                             faults=FaultPlan(seed=3, p_loss=0.3))),
+    "dropout": (_FIRE_CFG, dict(_FIRE_EXP, faults=_DROPOUT)),
+}
+
+
+def _used_pool(es, free: int, seed: int = 0):
+    """``es`` with a pool in use: a shuffled free ring whose ``free`` free
+    slots wrap past its end, and the other slots holding messages."""
+    m = es.msg_t.shape[0]
+    rng = np.random.default_rng(seed)
+    ring = rng.permutation(m).astype(np.int32)
+    head = m - 3
+    busy = ring[(head + np.arange(free, m)) % m]
+    msg_t = np.full(m, np.inf, np.float32)
+    msg_t[busy] = rng.uniform(0.0, 20.0, busy.size)
+    msg_w = np.zeros(es.msg_w.shape, np.float32)
+    msg_w[busy] = rng.normal(size=(busy.size, msg_w.shape[1]))
+    return es._replace(free_ring=jnp.asarray(ring), free_head=jnp.int32(head),
+                       free_n=jnp.int32(free), msg_t=jnp.asarray(msg_t),
+                       msg_w=jnp.asarray(msg_w))
+
+
+@pytest.mark.parametrize("case", sorted(_FIRE_CASES))
+def test_narrow_and_wide_fires_agree_bitwise(case, narrow_width):
+    """One ``fire()`` on the same used pool gives bit for bit the same pool
+    fields, free ring, counters, ``sent``, ``dropped`` and
+    ``dropped_fault`` whatever width its enqueue runs at: the default (16
+    units narrow), a width of 40 units, and 4N (every fire wide), for fires
+    of 0, 1, 16, 17 and 40 units; ``narrow_fires`` and ``wide_fires``
+    count the width each took."""
+    cfg, ekw = _FIRE_CASES[case]
+    ecfg = events.EventConfig(**ekw)
+    n, e = cfg.n_units, 8
+    assert (SinglePool().pack_scale(cfg, ecfg, e) is None) == (case == "lex")
+    state = afm.init(jax.random.PRNGKey(0), cfg)
+    es0 = events.init_events(state, cfg, ecfg, e, jax.random.PRNGKey(1))
+    m = es0.msg_t.shape[0]
+    es0 = _used_pool(es0, free=9 if "capacity" in ekw else m // 2)
+    rng = np.random.default_rng(1)
+    masks = []
+    for k in (0, 1, 16, 17, 40):
+        mask = np.zeros(n, bool)
+        mask[rng.choice(n, k, replace=False)] = True
+        masks.append(jnp.asarray(mask))
+    outs = {}
+    for units in (events._NARROW_WIDTH // 4, 40, n):
+        narrow_width(4 * units)
+        fire = jax.jit(events._make_round_fns(
+            cfg, ecfg, e, afm.search_exact, events._default_p,
+            events._default_l_c, i0=es0.i, far=state.far,
+            near=state.near)[3])
+        outs[units] = [fire(es0, mask, jnp.int32(3), jnp.float32(12.5),
+                            jnp.int32(2)) for mask in masks]
+    widths = ("narrow_fires", "wide_fires")
+    for units, runs in outs.items():
+        for out, ref in zip(runs, outs[n]):
+            for field in events.EventState._fields:
+                if field not in widths:
+                    np.testing.assert_array_equal(
+                        np.asarray(getattr(out, field)),
+                        np.asarray(getattr(ref, field)), err_msg=field)
+            k = int(out.sizes[3])              # units that fired
+            narrow = 0 < k <= units < n
+            assert int(out.narrow_fires) == narrow, (units, k)
+            assert int(out.wide_fires) == (k > 0 and not narrow), (units, k)
+    enqueued = [int(out.sent) for out in outs[n]]
+    assert enqueued[0] == 0 < enqueued[1] < enqueued[2]
+    if "capacity" in ekw:
+        assert int(outs[n][-1].dropped) > 0
+    if case == "loss":
+        assert int(outs[n][-1].dropped_fault) > 0
+    if case == "dropout":
+        assert int(outs[n][-1].sizes[3]) < 40     # dead units do not fire
+
+
+def test_fire_of_more_than_the_narrow_units_agrees_bitwise(narrow_width):
+    """A constant-latency cascade under the sandpile's p = 1 from a lattice
+    one short of theta everywhere: its fronts fire more units than the
+    narrow width takes, so the run meets both enqueue widths, and gives bit
+    for bit what a run with every enqueue wide gives."""
+    cfg = AFMConfig(side=12, dim=2, theta=4, l_s=0.1, i_max=16)
+    state = _unit_state(cfg)._replace(
+        c=jnp.full((cfg.n_units,), cfg.theta - 1, jnp.int32))
+    target = jnp.asarray([[66.0, 0.0], [30.0, 0.0]], jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    ecfg = events.EventConfig(latency="constant", delay=1.0)
+    runs = []
+    for width in (events._NARROW_WIDTH, 4 * cfg.n_units):
+        narrow_width(width)
+        runs.append(events.run_events(
+            state, target, keys, cfg, ecfg, search=_site_search,
+            p_fn=_p_one, l_c_fn=_l_c_const))
+    (st_d, aux_d, rep_d), (st_w, aux_w, rep_w) = runs
+    np.testing.assert_array_equal(np.asarray(st_d.w), np.asarray(st_w.w))
+    np.testing.assert_array_equal(np.asarray(st_d.c), np.asarray(st_w.c))
+    np.testing.assert_array_equal(np.asarray(aux_d.cascade_size),
+                                  np.asarray(aux_w.cascade_size))
+    widths = ("narrow_rounds", "narrow_fires", "wide_fires")
+    for field in events.EventReport._fields:
+        if field not in widths:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(rep_d, field)),
+                np.asarray(getattr(rep_w, field)), err_msg=field)
+    assert int(rep_d.narrow_fires) > 0 and int(rep_d.wide_fires) > 0
+    assert int(rep_w.narrow_fires) == 0
+    assert int(rep_w.wide_fires) == int(rep_d.narrow_fires) + int(
+        rep_d.wide_fires)
+    assert int(rep_d.dropped) == 0
+
+
+def test_fire_counters_count_the_enqueues():
+    """Under exponential latency every enqueue runs narrow: ``narrow_fires``
+    equals the number of ``fire()`` calls that fired a unit, counted here
+    round by round (a round's one ``fire()`` fired iff the cascade sizes
+    grew), and ``wide_fires`` is 0, in the round loop and in the report."""
+    cfg, num_events, ekw, _ = _CASE_BY_NAME["hot_exp"]
+    ecfg = events.EventConfig(**ekw)
+    state = afm.init(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (num_events, cfg.dim))
+    keys = jax.random.split(jax.random.PRNGKey(2), num_events)
+    lat = jax.random.PRNGKey(3)
+    es = events.init_events(state, cfg, ecfg, num_events, lat)
+    sample_round, delivery_round, pool_min, _ = map(
+        jax.jit, events._make_round_fns(
+            cfg, ecfg, num_events, afm.search_heuristic, _REGEN._p_hot,
+            events._default_l_c, i0=es.i, far=state.far, near=state.near))
+    firing_calls = 0
+    for ev in range(num_events + 1):
+        t_limit = ev * ecfg.sample_spacing if ev < num_events else np.inf
+        while True:
+            tmin, gmin, cmin, sel, have = pool_min(es)
+            if not (bool(have) and float(tmin) <= t_limit):
+                break
+            after = delivery_round(es, tmin, gmin, cmin, sel)
+            firing_calls += int(after.sizes.sum()) > int(es.sizes.sum())
+            es = after
+        if ev < num_events:
+            after = sample_round(es, x[ev], keys[ev])
+            firing_calls += int(after.sizes.sum()) > int(es.sizes.sum())
+            es = after
+    assert int(es.free_n) == es.msg_t.shape[0]        # drained
+    assert firing_calls > 0
+    assert int(es.narrow_fires) == firing_calls
+    assert int(es.wide_fires) == 0
+    _, _, rep = events.run_events(state, x, keys, cfg, ecfg, lat_key=lat,
+                                  p_fn=_REGEN._p_hot)
+    assert int(rep.rounds) == int(es.rounds)
+    assert int(rep.narrow_fires) == firing_calls
+    assert int(rep.wide_fires) == 0
+
+
 def test_zero_fast_path_dispatch_conditions():
     """The fused scan only takes over when it is provably equivalent."""
     ok = events._zero_fast_ok
@@ -628,8 +790,9 @@ def test_packed_key_and_lex_fallback_agree_bitwise():
 def test_zero_fast_path_equals_engine_on_seeded_10x10():
     """Live invariant behind the fast path: on a seeded 10x10 run the fused
     scan and the forced discrete-event engine agree bitwise — state, aux,
-    and the EventReport field for field. ``narrow_rounds`` counts how the
-    engine ran its delivery rounds, of which the fused scan runs none."""
+    and the EventReport field for field. ``narrow_rounds``,
+    ``narrow_fires`` and ``wide_fires`` count how the engine ran its
+    delivery rounds and enqueues, of which the fused scan runs none."""
     cfg = AFMConfig(side=10, dim=8, i_max=100, batch=1, e_factor=0.3)
     x = _tiny_data(dim=8, n=512, seed=11)
     key = jax.random.PRNGKey(42)
@@ -639,9 +802,12 @@ def test_zero_fast_path_equals_engine_on_seeded_10x10():
     np.testing.assert_array_equal(np.asarray(fast.state_.w),
                                   np.asarray(slow.state_.w))
     rf, rs = fast.backend.last_report, slow.backend.last_report
+    widths = ("narrow_rounds", "narrow_fires", "wide_fires")
     assert int(rf.narrow_rounds) == 0 < int(rs.narrow_rounds)
+    assert int(rf.narrow_fires) == 0 < int(rs.narrow_fires)
+    assert int(rf.wide_fires) == 0
     for field in events.EventReport._fields:
-        if field == "narrow_rounds":
+        if field in widths:
             continue
         np.testing.assert_array_equal(
             np.asarray(getattr(rf, field)), np.asarray(getattr(rs, field)),

@@ -17,7 +17,8 @@ Contracts under test:
 - on four forced devices under exponential latency, the mesh matches the
   plain reference of its semantics (``tests/mesh_ref.py``), teacher-forced,
   count for count, including its collective steps, halo messages and
-  narrow rounds; its narrow and worst-case delivery widths agree bitwise.
+  narrow rounds; its narrow and worst-case delivery widths agree bitwise,
+  and so do its narrow and wide enqueues of fires and halo arrivals.
 """
 import importlib.util
 import json
@@ -326,6 +327,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import sys
 import jax
+import jax.numpy as jnp
 import numpy as np
 from repro import obs
 from repro.api import AFMConfig
@@ -358,11 +360,39 @@ def runs(ecfg=ecfg):
     return out
 
 def flat(out):
-    # every output but narrow_rounds, which counts the width
+    # every output but the counts of the widths run
     return [np.asarray(a).tolist() for *_, st, aux, rep in out
             for a in (st.w, st.c, aux.gmu, aux.q2, aux.cascade_size,
-                      aux.waves) + tuple(rep._replace(narrow_rounds=0))]
+                      aux.waves) + tuple(rep._replace(
+                          narrow_rounds=0, narrow_fires=0, wide_fires=0))]
 
+def set_width(width):
+    events._NARROW_WIDTH = width
+    events._compiled_runner.cache_clear()
+
+def fires(out):
+    # (narrow enqueues, wide enqueues, firing incidents) of each call
+    return [[int(rep.narrow_fires), int(rep.wide_fires),
+             int(np.sum(aux.cascade_size))] for *_, aux, rep in out]
+
+# a sandpile cascade over two bands of a side-16 map: every unit one short
+# of theta, p = 1, constant latency; its fronts fire more units of a band
+# at once than the narrow width takes, and cross the band boundary
+big = AFMConfig(side=16, dim=2, theta=4, i_max=256)
+p_one = lambda i, c: jnp.float32(1.0)
+
+def cascade():
+    st = afm.init(k_init, big)
+    st = st._replace(c=jnp.full((big.n_units,), big.theta - 1, jnp.int32))
+    x = st.w[jnp.asarray([8 * 16 + 8, 3 * 16 + 5])]
+    steps = jax.random.split(k_steps, 2)
+    st, aux, rep = events.run_events(
+        st, x, steps, big, events.EventConfig(latency="constant", delay=1.0),
+        search=afm.search_exact, p_fn=p_one, lat_key=k_lat,
+        placement="mesh", shards=2)
+    return [(x, steps, k_lat, st, aux, rep)]
+
+default_width = events._NARROW_WIDTH
 narrow = runs()
 again = runs()
 fn = events._compiled_runner(cfg, ecfg, 4, afm.search_exact,
@@ -377,13 +407,21 @@ scopes = {s: any(f"/{s}/" in line for line in names)
 # under constant latency a fire's messages share a round; at a narrow width
 # of 2 the larger rounds take the cond's wide branch
 const = events.EventConfig(latency="constant", delay=1.0)
-events._NARROW_WIDTH = 2
-events._compiled_runner.cache_clear()
+set_width(2)
 mixed = runs(const)
-events._NARROW_WIDTH = MeshPlacement(4).pool_capacity(cfg, ecfg)
-events._compiled_runner.cache_clear()
+set_width(MeshPlacement(4).pool_capacity(cfg, ecfg))
 wide = runs()
 const_wide = runs(const)
+set_width(4 * big.n_units)
+cascade_wide = cascade()
+set_width(default_width)
+cascade_default = cascade()
+# at a width of 8, fires of up to 2 units and halos of up to 8 arrivals
+# take the narrow enqueue, larger ones the wide
+set_width(8)
+small = runs()
+small_const = runs(const)
+cascade_small = cascade()
 
 p = dict(n=cfg.n_units, side=cfg.side, dim=cfg.dim, theta=cfg.theta,
          l_s=cfg.l_s, c_o=cfg.c_o, c_s=cfg.c_s, c_m=cfg.c_m, c_d=cfg.c_d,
@@ -415,6 +453,15 @@ print(json.dumps({
     "mixed_delivery_rounds": [int(o[5].rounds - o[5].samples) for o in mixed],
     "const_wide_narrow_rounds": [int(o[5].narrow_rounds) for o in const_wide],
     "repeat_bitwise": flat(narrow) == flat(again),
+    "narrow_fires": fires(narrow),
+    "small_equals_narrow": flat(small) == flat(narrow),
+    "small_fires": fires(small),
+    "small_const_equals_wide": flat(small_const) == flat(const_wide),
+    "cascade_default_equals_wide": flat(cascade_default) == flat(cascade_wide),
+    "cascade_small_equals_wide": flat(cascade_small) == flat(cascade_wide),
+    "cascade_fires": [fires(o)[0] for o in (cascade_default, cascade_small,
+                                             cascade_wide)],
+    "cascade_halo_sent": int(cascade_wide[0][5].halo_sent),
     "scopes": scopes,
 }))
 """
@@ -425,8 +472,9 @@ def mesh_vs_reference():
     """One 4-device subprocess: the mesh at side 8, D = 16, exponential
     latency, two consecutive runs of 96 samples, replayed by the plain
     reference (``tests/mesh_ref.py``) teacher-forced by the program's units;
-    the same runs again, and with every delivery round at the worst-case
-    width; and the runner's compiled HLO."""
+    the same runs again, and with every delivery round and enqueue at the
+    worst-case width or at a width of 8; a side-16 sandpile cascade over
+    two bands at three widths; and the runner's compiled HLO."""
     env = dict(os.environ, PYTHONPATH=os.path.join(_HERE, "..", "src"),
                MESH_REF_DIR=_HERE)
     env.pop("XLA_FLAGS", None)
@@ -481,6 +529,40 @@ def test_mesh_narrow_and_wide_rounds_agree_bitwise(mesh_vs_reference):
     for narrow, rounds in zip(res["mixed_narrow_rounds"],
                               res["mixed_delivery_rounds"]):
         assert 0 < narrow < rounds, res
+
+
+def test_mesh_fire_and_halo_widths_agree_bitwise(mesh_vs_reference):
+    """A band's enqueue gives bit for bit the same run at every width: at a
+    width of 8 (fires of up to 2 units and halos of up to 8 arrivals
+    narrow) under exponential latency, where the run also equals the plain
+    reference's, and under constant latency; and in a sandpile cascade over
+    two bands of a side-16 map, whose fronts fire more units of a band than
+    the narrow width takes and send halo bursts across the boundary, at
+    the default width and at 8, against every enqueue wide."""
+    res = mesh_vs_reference
+    assert res["small_equals_narrow"]
+    assert res["small_const_equals_wide"]
+    assert res["cascade_default_equals_wide"]
+    assert res["cascade_small_equals_wide"]
+    assert res["cascade_halo_sent"] > 0
+
+
+def test_mesh_fire_counters_count_the_enqueues(mesh_vs_reference):
+    """``narrow_fires`` and ``wide_fires`` count the enqueues of the bands'
+    ``fire()`` calls that fired a unit. Under exponential latency a band's
+    round fires at most one unit, so they add up to the firing incidents:
+    all narrow at a width of 8; all wide at the default width, which is no
+    narrower than a side-8 band's 4L = 64 candidates. The cascade's large
+    fronts run wide at the default width and its small ones narrow."""
+    res = mesh_vs_reference
+    for (narrow, wide, fired), (small_n, small_w, small_fired) in zip(
+            res["narrow_fires"], res["small_fires"]):
+        assert narrow == 0 and wide == fired > 0
+        assert small_w == 0 and small_n == small_fired == fired
+    default, small, every = res["cascade_fires"]
+    assert default[0] > 0 and default[1] > 0
+    assert every[0] == 0
+    assert every[1] == default[0] + default[1] == small[0] + small[1]
 
 
 def test_mesh_exponential_replays_bitwise(mesh_vs_reference):

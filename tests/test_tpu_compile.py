@@ -12,6 +12,8 @@ time, so no import, ``skipif`` or ``parametrize`` may touch it. The
 persistent compilation cache is off around the compiles (entries written
 for a described chip cannot be read back without one).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -140,3 +142,93 @@ def test_mesh_event_runner_compiles_for_v5e_2x2(topo, monkeypatch):
         _shape(repl, (2,), jnp.uint32)).compile().as_text()
     assert "collective-permute" in text
     assert "all-reduce" in text
+
+
+def _hlo_computations(text):
+    """Compiled HLO text -> ({computation: instruction lines},
+    {instruction: result type})."""
+    comps, types, cur = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line)
+            inst = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (\S+)", line)
+            if inst:
+                types[inst.group(1)] = inst.group(2)
+    return comps, types
+
+
+def _called(comps, name):
+    """Every computation ``name`` runs, itself included."""
+    seen, todo = set(), [name]
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps.get(comp, ()):
+            for group in re.findall(
+                    r"(?:calls|to_apply|body|condition|branch_computations|"
+                    r"called_computations)=(\{[^}]*\}|%[\w.\-]+)", line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+def test_event_runner_narrow_enqueue_writes_the_pool_in_place(one_chip):
+    """The single-pool event runner at the async cell's shapes (side 40,
+    D = 784, exponential latency, exact search): each of ``fire()``'s
+    three-way enqueue branches (skip, narrow, wide) runs its narrow branch
+    as a payload scatter of at most 64 rows into the f32[12800, 784] pool,
+    in place: the branch holds no copy of the pool. The wide branch writes
+    all 4N = 6,400 candidates."""
+    from repro.core import afm, events
+    from repro.core.placement import SinglePool
+
+    cfg = AFMConfig(side=SIDE, dim=DIM, batch=1, i_max=40 * SIDE * SIDE)
+    ecfg = events.EventConfig(latency="exponential", delay=1.0)
+    e = 64
+    runner = SinglePool().build_runner(cfg, ecfg, e, afm.search_exact,
+                                       events._default_p,
+                                       events._default_l_c)
+    state = jax.tree.map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda k: afm.init(k, cfg), jax.random.PRNGKey(0)))
+    text = jax.jit(runner).lower(
+        state, _shape(one_chip, (e, DIM)),
+        _shape(one_chip, (e, 2), jnp.uint32),
+        _shape(one_chip, (2,), jnp.uint32)).compile().as_text()
+    comps, types = _hlo_computations(text)
+    pool = f"f32[{8 * cfg.n_units},{DIM}]"
+
+    def payload_rows(branch):
+        """Rows of each scatter into the payload pool the branch runs."""
+        rows = []
+        for comp in _called(comps, branch):
+            for line in comps[comp]:
+                m = re.search(r"= (\S+) scatter\(%[\w.\-]+, %[\w.\-]+, "
+                              r"%([\w.\-]+)\)", line)
+                if m and m.group(1).startswith(pool):
+                    rows.append(int(re.match(r"f32\[(\d+),",
+                                             types[m.group(2)]).group(1)))
+        return rows
+
+    def pool_copies(branch):
+        return [line for comp in _called(comps, branch)
+                for line in comps[comp]
+                if re.search(r"= \(?" + re.escape(pool)
+                             + r"\S* .*\bcopy(-start)?\(", line)]
+
+    switches = [re.search(r"branch_computations=\{([^}]*)\}", line)
+                for lines in comps.values() for line in lines
+                if " conditional(" in line and pool in line]
+    branches = [re.findall(r"%([\w.\-]+)", m.group(1))
+                for m in switches if m]
+    enqueues = [b for b in branches if len(b) == 3]
+    assert enqueues, branches
+    for skip, narrow, wide in enqueues:
+        assert payload_rows(skip) == []
+        assert payload_rows(narrow) and max(payload_rows(narrow)) <= 64
+        assert pool_copies(narrow) == []
+        assert 4 * cfg.n_units in payload_rows(wide)

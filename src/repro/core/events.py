@@ -23,8 +23,12 @@ are first-class.
 Execution pops *rounds* — all messages sharing the minimal ``(time,
 generation, cascade-id)`` key, or the next sample arrival — and each round's
 handler is data-parallel over the messages actually in the round, not over
-the whole map. Three statically-chosen runners implement the same round
-semantics (DESIGN.md §7 "round cost model"):
+the whole map. The pool itself — round selection, the send side and the
+delivery round's receiver side, each at the width its own count asks for —
+is ``repro.core.pool``, which the mesh placement's bands share; this module
+holds the single pool's state, its packed round key and its runners. Three
+statically-chosen runners implement the same round semantics (DESIGN.md §7
+"round cost model"):
 
 - **fused zero-latency scan** — ``latency='zero'`` runs replay the
   ``reference`` backend's fused step scan op-for-op (plus an accounting
@@ -67,103 +71,14 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core import afm as afm_lib
 from repro.core import cascade as cascade_lib
+from repro.core import pool as pool_lib
 from repro.core import schedules
 from repro.core.afm import AFMConfig, AFMState
-from repro.core.placement import base as placement_base
-from repro.core.placement import single as placement_single
 from repro.faults import FaultPlan
 
 LATENCIES = ("zero", "constant", "exponential")
 ENGINES = ("auto", "event")
 KERNELS = ("staged", "fused", "fused-interpret")
-
-# The pool-min selectors, packing rule, and +inf sentinel moved behind the
-# placement seam (``repro.core.placement.single``); these aliases keep the
-# engine's internals — and the golden parity suite that imports them —
-# pointing at the single source of truth.
-_INF_BITS = placement_single.INF_BITS
-_key_scale = placement_single.key_scale
-_pool_min_lex = placement_single.pool_min_lex
-_pool_min_packed = placement_single.pool_min_packed
-
-#: Static width of a delivery round that selects few messages. An
-#: exponential-latency round delivers one message and a constant-latency one
-#: the output of one ``fire()`` (≤ 4 per fired unit), so 64 covers every
-#: exponential round and a fire of up to 16 units; wider rounds run at the
-#: worst-case width ``k_round``.
-_NARROW_WIDTH = 64
-
-
-def _compress(mask, width: int):
-    """Ascending positions of the first ``width`` True entries of the 1-D
-    ``mask``, filled with ``mask.size``: ``jnp.nonzero(mask, size=width,
-    fill_value=mask.size)[0]``. ``jnp.nonzero`` counts the entries with a
-    scatter-add over all of them, which the TPU runs serially (about 116 µs
-    at 12,800 entries on a v5e); at a narrow width, the j-th position is
-    the count of entries whose running True count is at most j, one fused
-    ``width × size`` compare and sum (about 3 µs there)."""
-    if width > _NARROW_WIDTH:
-        return jnp.nonzero(mask, size=width, fill_value=mask.size)[0]
-    rank = jnp.cumsum(mask, dtype=jnp.int32)
-    return jnp.sum(rank[None, :] <= jnp.arange(width, dtype=jnp.int32)[:, None],
-                   axis=1, dtype=jnp.int32)
-
-
-def _fire_units(n_local: int) -> int:
-    """Most fired units an enqueue takes at the narrow width (each sends at
-    most 4 candidates), or 0 where ``n_local`` units' ``4·n_local``
-    candidates are no wider than that: the enqueue then has one width."""
-    units = _NARROW_WIDTH // 4
-    return units if _NARROW_WIDTH < 4 * n_local else 0
-
-
-def _fire_candidates(fired, units: int):
-    """The candidates of the first ``units`` fired units at a static width
-    ``4 · units``: ids ``4·unit + direction`` in (unit, direction) order,
-    which is the order of the wide enqueue's ``(units, 4)`` reshape, so the
-    r-th valid candidate is the same at both widths. Returns (ids clipped
-    into range, real) — fill entries past the fired units are not real."""
-    unit = _compress(fired, units)
-    ids = (unit[:, None] * 4 + jnp.arange(4, dtype=jnp.int32)).reshape(-1)
-    size = 4 * fired.shape[0]
-    return jnp.minimum(ids, size - 1), ids < size
-
-
-def _enqueue_by_count(count, most: int, skip, enqueue, pool):
-    """Run an enqueue at the width its ``count`` (of fired units, or of
-    valid arrivals) asks for: ``skip(pool)`` when the count is 0,
-    ``enqueue(True, pool)`` when it is at most ``most``, and
-    ``enqueue(False, pool)`` when it is more (every nonzero count where
-    ``most`` is 0). Returns (pool, whether the narrow width ran)."""
-    wide = functools.partial(enqueue, False)
-    if not most:
-        return jax.lax.cond(count > 0, wide, skip, pool), jnp.bool_(False)
-    return (jax.lax.switch(jnp.minimum(count, 1) + (count > most),
-                           (skip, functools.partial(enqueue, True), wide),
-                           pool),
-            (count > 0) & (count <= most))
-
-
-def _allocate(valid, free_ring, free_head, free_n):
-    """Pool slots off the free ring for the candidates ``valid`` marks, at
-    its static width: the r-th valid candidate takes the r-th free slot and
-    candidates past the free count get the out-of-range slot (their
-    ``mode="drop"`` scatters write nothing). Returns (slot, allocated,
-    overflow)."""
-    m = free_ring.shape[0]
-    rank = jnp.cumsum(valid, dtype=jnp.int32) - 1
-    can = valid & (rank < free_n)
-    slot = jnp.where(can, free_ring[(free_head + rank) % m], m)
-    nalloc = jnp.sum(can, dtype=jnp.int32)
-    return slot, nalloc, jnp.sum(valid, dtype=jnp.int32) - nalloc
-
-
-#: Direction codes, from the *receiver*'s perspective, matching the slot
-#: order of ``core.cascade._shift4``: 0 = from row+1 (below), 1 = from row-1
-#: (above), 2 = from col+1 (right), 3 = from col-1 (left). A sender's 4
-#: outgoing messages use its ``near`` table order (up, down, left, right),
-#: which lands on exactly these receiver slots — the same (4, side, side)
-#: Bernoulli tensor indexes both implementations identically.
 
 
 @dataclasses.dataclass(frozen=True)
@@ -367,13 +282,17 @@ class EventReport(NamedTuple):
         return self.dropped - self.stranded
 
 
+def wave_cap(cfg: AFMConfig) -> int:
+    """The engine's effective cascade wave bound (``None`` -> 8·side²)."""
+    return 8 * cfg.side * cfg.side if cfg.max_waves is None else cfg.max_waves
+
+
 def _resolve(cfg: AFMConfig, ecfg: EventConfig, num_events: int):
-    """Static derived quantities: (pool size M, alloc width K, wave cap,
-    round cap). Pool sizing and the wave cap are the single-pool placement's
-    rules (``repro.core.placement.single``)."""
-    m = placement_single.pool_capacity(cfg, ecfg)
+    """Static derived quantities of the single pool: (pool size M, one
+    fire's candidates K, wave cap, round cap)."""
+    m = pool_lib.capacity(ecfg, cfg.n_units)
     k = min(4 * cfg.n_units, m)
-    max_waves = placement_single.wave_cap(cfg)
+    max_waves = wave_cap(cfg)
     max_rounds = (ecfg.max_rounds if ecfg.max_rounds is not None
                   else num_events * (max_waves + 2) + 1)
     # the round counter is int32; a huge max_waves would overflow the
@@ -427,25 +346,24 @@ def _default_l_c(i, cfg: AFMConfig):
 
 def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
                     search: Callable, p_fn: Callable, l_c_fn: Callable,
-                    i0, far, near, placement=None):
+                    i0, far, near):
     """Build (sample_round, delivery_round, pool_min, fire) as closures.
 
     ``i0`` is the run's starting sample count: cascade ``cid`` uses the
     schedules evaluated at ``i0 + cid`` throughout its lifetime — exactly
     the value its own sample round saw, matching the reference semantics
     where one step's cascade runs entirely under that step's l_c / p_i.
-    ``far`` / ``near`` are the loop-invariant lattice tables. Round
-    selection, key packing, and the fire-candidate routing tables come
-    from the ``placement`` (default ``SinglePool``).
+    ``far`` / ``near`` are the loop-invariant lattice tables. The pool's
+    send and receive sides are ``core.pool``'s; what is the single pool's
+    own is the packed round key, round counting and wave gating on
+    ``wcount``.
     """
-    placement = placement_base.resolve_placement(placement)
-    n, d, side, theta = cfg.n_units, cfg.dim, cfg.side, cfg.theta
+    n, side, theta = cfg.n_units, cfg.side, cfg.theta
     m, k_sel, max_waves, _ = _resolve(cfg, ecfg, num_events)
     # fault-plan closures (repro.faults): each axis is a *static* Python
     # branch, so an inactive plan builds the exact fault-free graph — the
     # golden-bitwise contract is structural, not numeric luck
     plan = ecfg.plan
-    loss_on = ecfg.fault_active and plan.p_loss > 0.0
     dead_on = ecfg.fault_active and plan.dropout_active
     if dead_on:
         dead_sel = plan.dead_units(n)
@@ -455,36 +373,32 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         def dead_at(t):
             """(N,) bool — units dead at simulated time ``t``."""
             return dead_sel & (t >= d_lo) & (t < d_hi)
-    scale = placement.pack_scale(cfg, ecfg, num_events)
-    selector = placement.make_selector(cfg, ecfg, num_events)
-    # a delivery round selects one (t, gen, cid): at zero/constant latency
-    # that is one fire()'s output (≤ 4N messages); exponential delays can in
-    # principle tie across fires, so the selection width covers the pool.
-    # Rounds that select at most k_narrow messages run at that width instead.
-    k_round = m if ecfg.latency == "exponential" else k_sel
-    k_narrow = min(k_round, _NARROW_WIDTH)
-    src4, dst4, dirs4 = placement.routing(near)
+    # round selection: the packed single-lane min whenever (gen, cid) fits
+    # one uint32, else the exact lexicographic 3-field min
+    scale = pool_lib.key_scale(num_events, max_waves)
+    widths = pool_lib.round_widths(ecfg, m, k_sel)
+    tables = pool_lib.routes(near.reshape(-1))
+    fire_units = pool_lib.fire_units(n)
 
     def pool_min(es: EventState):
         with jax.named_scope(obs.EVENTS_POOL):
-            return selector(es.msg_t, es.msg_key, es.msg_gen, es.msg_cid)
+            if scale is None:
+                return pool_lib.pool_min_lex(es.msg_t, es.msg_gen, es.msg_cid)
+            return pool_lib.pool_min_packed(es.msg_t, es.msg_key, scale)
 
-    fire_units = _fire_units(n)
+    def round_key(gen, cid) -> dict:
+        gen = jnp.asarray(gen, jnp.int32)
+        cid = jnp.asarray(cid, jnp.int32)
+        if scale is None:
+            return {"msg_gen": gen, "msg_cid": cid}
+        return {"msg_key": gen.astype(jnp.uint32) * jnp.uint32(scale)
+                + cid.astype(jnp.uint32)}
 
     def fire(es: EventState, fired, cid, t, gen) -> EventState:
-        """Broadcast-after-theta: ``fired`` units reset their counters and
-        enqueue weight messages to their near neighbours (payload = the
-        sender's current w), timestamped by the latency model. Pool slots
-        come off the free ring: the r-th valid candidate takes the r-th
-        free slot, candidates past the free count are dropped (counted).
-
-        The enqueue's static width follows the fire's own count: a fire of
-        at most ``fire_units`` units (every fire of an exponential-latency
-        run) enqueues their ≤ 4·``fire_units`` candidates, a larger one all
-        4N, and a fire of none skips the enqueue. The narrow candidates are
-        the wide ones in the same order, with their latency and loss draws
-        taken from the same (4N,) tensors, so the width never shows in the
-        pool (``narrow_fires`` / ``wide_fires`` count it).
+        """Broadcast-after-theta (``core.pool.fire``): ``fired`` units reset
+        their counters and enqueue weight messages to their near neighbours
+        (payload = the sender's current w), timestamped by the latency
+        model, at a width that follows the fire's own count.
 
         Faults: dead units neither fire nor count as firing incidents (a
         unit whose counter crossed ``theta`` while dead fires on rejoin at
@@ -493,89 +407,8 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         the conservation identity sees every attempted broadcast."""
         if dead_on:
             fired = fired & ~dead_at(t)
-        nfired = jnp.sum(fired, dtype=jnp.int32)
-        sizes = es.sizes.at[cid].add(nfired)
-        c = jnp.where(fired, 0, es.c)
-        # The lat_key split is unconditional — the exponential stream
-        # advances once per fire() call whether or not anything fired,
-        # matching the original engine's PRNG discipline bit-for-bit.
-        lat_key = es.lat_key
-        if ecfg.latency == "exponential":
-            lat_key, lat_sub = jax.random.split(lat_key)
-        else:
-            lat_sub = lat_key
-        gen_u = jnp.asarray(gen, jnp.int32)
-        cid_u = jnp.asarray(cid, jnp.int32)
-
-        # the branches close over exactly the pool fields enqueue mutates,
-        # so the skip branch is a no-op over small operands (not the full
-        # EventState — E-sized aux arrays never enter the conditional)
-        pool = (es.msg_t, es.msg_key, es.msg_gen, es.msg_cid, es.msg_dst,
-                es.msg_dir, es.msg_w, es.free_head, es.free_n, es.dropped,
-                es.sent, es.dropped_fault, es.fault_key)
-
-        def enqueue(narrow: bool, pool):
-            (msg_t, msg_key, msg_gen, msg_cid, msg_dst, msg_dir, msg_w,
-             free_head, free_n, drop0, sent0, dfault0, fkey) = pool
-            # PRNG draws keep their (4N,) shapes at either width
-            if loss_on:
-                fkey, sub = jax.random.split(fkey)
-                keep = jax.random.uniform(sub, (4 * n,)) >= plan.p_loss
-            if ecfg.latency == "exponential":
-                delay = jax.random.exponential(lat_sub, (4 * n,)) * ecfg.delay
-            elif ecfg.latency == "constant":
-                delay = jnp.full((4 * n,), ecfg.delay, jnp.float32)
-            else:
-                delay = jnp.zeros((4 * n,), jnp.float32)
-            if narrow:
-                ids, real = _fire_candidates(fired, fire_units)
-                dst, dirs, src = dst4[ids], dirs4[ids], src4[ids]
-                valid = real & (dst >= 0)
-                delay = delay[ids]
-                if loss_on:
-                    keep = keep[ids]
-            else:
-                # candidate messages: (N, 4) in near-table order (up, down,
-                # left, right) == receiver direction codes (below, above,
-                # right, left)
-                dst, dirs, src = dst4, dirs4, src4
-                valid = (fired[:, None] & (near >= 0)).reshape(-1)   # (4N,)
-            sent0 = sent0 + jnp.sum(valid, dtype=jnp.int32)
-            if loss_on:
-                dfault0 = dfault0 + jnp.sum(valid & ~keep, dtype=jnp.int32)
-                valid = valid & keep
-            slot, nalloc, dropped = _allocate(valid, es.free_ring, free_head,
-                                              free_n)
-            if scale is not None:
-                packed = (gen_u.astype(jnp.uint32) * jnp.uint32(scale)
-                          + cid_u.astype(jnp.uint32))
-                msg_key = msg_key.at[slot].set(packed, mode="drop")
-            else:
-                msg_gen = msg_gen.at[slot].set(gen_u, mode="drop")
-                msg_cid = msg_cid.at[slot].set(cid_u, mode="drop")
-            return (msg_t.at[slot].set(t + delay, mode="drop"),
-                    msg_key, msg_gen, msg_cid,
-                    msg_dst.at[slot].set(dst, mode="drop"),
-                    msg_dir.at[slot].set(dirs, mode="drop"),
-                    msg_w.at[slot].set(es.w[src], mode="drop"),
-                    (free_head + nalloc) % m, free_n - nalloc,
-                    drop0 + dropped, sent0, dfault0, fkey)
-
-        # most rounds fire nothing: skip the pool scatters entirely then
-        with jax.named_scope(obs.EVENTS_POOL):
-            pool, narrow = _enqueue_by_count(nfired, fire_units, lambda p: p,
-                                             enqueue, pool)
-        (msg_t, msg_key, msg_gen, msg_cid, msg_dst, msg_dir, msg_w,
-         free_head, free_n, dropped, sent, dfault, fault_key) = pool
-        return es._replace(
-            c=c, sizes=sizes, lat_key=lat_key,
-            msg_t=msg_t, msg_key=msg_key, msg_gen=msg_gen, msg_cid=msg_cid,
-            msg_dst=msg_dst, msg_dir=msg_dir, msg_w=msg_w,
-            free_head=free_head, free_n=free_n, dropped=dropped,
-            sent=sent, dropped_fault=dfault, fault_key=fault_key,
-            narrow_fires=es.narrow_fires + narrow.astype(jnp.int32),
-            wide_fires=es.wide_fires
-            + ((nfired > 0) & ~narrow).astype(jnp.int32))
+        return pool_lib.fire(es, fired, cid, t, round_key(gen, cid), tables,
+                             fire_units, ecfg)
 
     def sample_round(es: EventState, sample, step_key) -> EventState:
         """Deliver the next sample: search routes it, the GMU adapts
@@ -636,116 +469,22 @@ def _make_round_fns(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         return es
 
     def delivery_round(es: EventState, tmin, gmin, cmin, sel) -> EventState:
-        """Deliver one round of weight broadcasts (one cascade wave): every
-        receiver adapts by the merged rule, is Bernoulli-driven once per
-        received message, and newly super-threshold receivers fire.
-
-        Work is sized by the round, not the map: the selected slots are
-        compressed out of the pool, their payloads segment-summed per
-        receiver in direction-slot order (bitwise the same sum order as
-        ``core.cascade._shift_sum``), and the weight update is a row scatter
-        over the receiver units. The static width of that work is
-        ``k_narrow`` when the round's own count allows it (every exponential
-        round, most constant ones) and ``k_round`` otherwise; both widths
-        add the same operands in the same order, so the choice never shows
-        in the result. The (4, side, side) Bernoulli tensor still comes
-        whole from the cascade's own key chain — PRNG shapes are part of
-        the bitwise contract.
-        """
+        """Deliver one round of weight broadcasts (one cascade wave,
+        ``core.pool.deliver``): every receiver adapts by the merged rule, is
+        Bernoulli-driven once per received message, and newly
+        super-threshold receivers fire while the cascade is under its wave
+        cap. The (4, side, side) Bernoulli tensor comes whole from the
+        cascade's own key chain — PRNG shapes are part of the bitwise
+        contract."""
         cid = cmin
         sched_i = i0 + cid
-        l_c = l_c_fn(sched_i, cfg)
-        p_i = p_fn(sched_i, cfg)
-        ck, sub = jax.random.split(es.casc_key[cid])
         k_wave = es.wcount[cid] + 1
-        bern = (jax.random.uniform(sub, (4, side, side)) < p_i).reshape(4, n)
-        nsel = jnp.sum(sel, dtype=jnp.int32)
-
-        def receive(width, _=None):
-            """The receiver side at static ``width`` ≥ ``nsel``: returns
-            (w, c, n_recv, ndeliv, free_ring)."""
-            # compress the selected messages: (width,) slot ids, fill = m
-            with jax.named_scope(obs.EVENTS_POOL):
-                idx = _compress(sel, width)
-                taken = idx < m
-                ii = jnp.minimum(idx, m - 1)
-                dsts = jnp.where(taken, es.msg_dst[ii], n)  # n -> dropped
-                dirs = jnp.where(taken, es.msg_dir[ii], 0)
-                ws = es.msg_w[ii]                           # (width, D)
-            with jax.named_scope(obs.EVENTS_DELIVER):
-                ok = taken
-                if dead_on:
-                    # messages addressed to a dead unit are consumed
-                    # (their slots free normally) but not delivered: no
-                    # adapt, no drive, no clock/event stamp — they count
-                    # as ``dropped_fault``
-                    ok = ok & ~dead_at(tmin)[jnp.minimum(dsts, n - 1)]
-                # counter drive: one Bernoulli per received message,
-                # from the wave's (4, N) tensor by (direction, receiver)
-                drive = jnp.where(ok, bern[dirs, jnp.minimum(dsts, n - 1)],
-                                  False)
-                c = es.c.at[dsts].add(drive.astype(jnp.int32), mode="drop")
-                n_recv = jnp.zeros((n,), jnp.int32).at[dsts].add(
-                    ok.astype(jnp.int32), mode="drop")
-                # unique receiver rows (sorted, fill = n), ≤ one per message
-                ridx = _compress(n_recv > 0, width)
-                pos = jnp.searchsorted(ridx, dsts)      # msg -> receiver row
-                acc = jnp.zeros((width, d), jnp.float32)
-                for s4 in range(4):                     # direction-slot order
-                    acc = acc.at[jnp.where(ok & (dirs == s4), pos, width)
-                                 ].add(ws, mode="drop")
-                # full receiver rows via the same elementwise chain as the
-                # dense form (w + l_c*(S - nf*w)) so XLA emits the same fma
-                # pattern, then a row scatter-set (ridx rows are unique)
-                rv = jnp.minimum(ridx, n - 1)
-                nf = n_recv[rv].astype(es.w.dtype)
-                wr = es.w[rv]
-                w_rows = wr + l_c * (acc - nf[:, None] * wr)
-                w = es.w.at[ridx].set(w_rows, mode="drop")
-            # ``ok`` excludes dead receivers; the gap vs the nsel consumed
-            # slots is the dead-receiver fault count
-            ndeliv = jnp.sum(ok, dtype=jnp.int32) if dead_on else nsel
-            # free the taken slots (dead receivers' too): push their ids
-            # onto the ring tail, in ascending slot order
-            with jax.named_scope(obs.EVENTS_POOL):
-                tail = jnp.where(
-                    taken,
-                    (es.free_head + es.free_n
-                     + jnp.arange(width, dtype=jnp.int32)) % m, m)
-                free_ring = es.free_ring.at[tail].set(idx, mode="drop")
-            return w, c, n_recv, ndeliv, free_ring
-
-        if k_narrow < k_round:
-            narrow = nsel <= k_narrow
-            w, c, n_recv, ndeliv, free_ring = jax.lax.cond(
-                narrow, functools.partial(receive, k_narrow),
-                functools.partial(receive, k_round), None)
-            narrow_rounds = es.narrow_rounds + narrow.astype(jnp.int32)
-        else:
-            w, c, n_recv, ndeliv, free_ring = receive(k_round)
-            narrow_rounds = es.narrow_rounds
-        received = n_recv > 0
-        extra = {}
-        if dead_on:
-            extra["dropped_fault"] = es.dropped_fault + (nsel - ndeliv)
-        with jax.named_scope(obs.EVENTS_POOL):
-            msg_t = jnp.where(sel, jnp.inf, es.msg_t)
-        es = es._replace(
-            w=w, c=c, t=tmin,
-            clock=jnp.where(received, tmin, es.clock),
-            nevents=es.nevents + n_recv,
-            msg_t=msg_t,
-            free_ring=free_ring,
-            free_n=es.free_n + nsel,
-            casc_key=es.casc_key.at[cid].set(ck),
-            wcount=es.wcount.at[cid].set(k_wave),
-            deliveries=es.deliveries + ndeliv,
-            rounds=es.rounds + 1,
-            narrow_rounds=narrow_rounds,
-            **extra,
-        )
-        new_fired = (c >= theta) & received
-        allowed = new_fired & (k_wave < max_waves)
+        es, received = pool_lib.deliver(
+            es, tmin, cid, sel, (side, side), p_fn(sched_i, cfg),
+            l_c_fn(sched_i, cfg), widths, dead_at(tmin) if dead_on else None)
+        es = es._replace(t=tmin, wcount=es.wcount.at[cid].set(k_wave),
+                         rounds=es.rounds + 1)
+        allowed = (es.c >= theta) & received & (k_wave < max_waves)
         return fire(es, allowed, cid, tmin, gmin + 1)
 
     return sample_round, delivery_round, pool_min, fire
@@ -929,8 +668,7 @@ def _make_fused_zero(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
 
 
 def _make_engine(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
-                 search: Callable, p_fn: Callable, l_c_fn: Callable,
-                 placement=None):
+                 search: Callable, p_fn: Callable, l_c_fn: Callable):
     """The default runner: an outer scan over the E sample arrivals with an
     inner while_loop that drains all due messages before each arrival (and a
     final drain to quiescence). Identical round order to the budgeted loop:
@@ -943,7 +681,7 @@ def _make_engine(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         es0 = init_events(state, cfg, ecfg, e, lat_key)
         sample_round, delivery_round, pool_min, _ = _make_round_fns(
             cfg, ecfg, e, search, p_fn, l_c_fn, i0=es0.i,
-            far=state.far, near=state.near, placement=placement)
+            far=state.far, near=state.near)
 
         def drain(es, t_limit):
             # round_cap is a safety net against engine bugs, not a semantic
@@ -974,8 +712,7 @@ def _make_engine(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
 
 
 def _make_budgeted(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
-                   search: Callable, p_fn: Callable, l_c_fn: Callable,
-                   placement=None):
+                   search: Callable, p_fn: Callable, l_c_fn: Callable):
     """Budgeted runner (``max_rounds`` set): one while_loop popping a round
     per iteration under a global round budget — the original PR-4 loop
     structure, kept for its exact truncation accounting."""
@@ -987,7 +724,7 @@ def _make_budgeted(cfg: AFMConfig, ecfg: EventConfig, num_events: int,
         es0 = init_events(state, cfg, ecfg, e, lat_key)
         sample_round, delivery_round, pool_min, _ = _make_round_fns(
             cfg, ecfg, e, search, p_fn, l_c_fn, i0=es0.i,
-            far=state.far, near=state.near, placement=placement)
+            far=state.far, near=state.near)
 
         def cond(es):
             return ((es.ev < e) | (es.free_n < m)) & (es.rounds < max_rounds)
@@ -1103,6 +840,9 @@ def run_events(state: AFMState, samples: jnp.ndarray, step_keys: jnp.ndarray,
                 narrow_rounds=zero, narrow_fires=zero, wide_fires=zero)
     if lat_key is None:
         lat_key = jax.random.PRNGKey(lat_seed)
+    # the one reference from the engine up to the placements above it
+    from repro.core.placement import base as placement_base
+
     pl = placement_base.resolve_placement(placement, shards=shards)
     fn = _compiled_runner(cfg, ecfg, e, search, p_fn, l_c_fn, bool(donate),
                           pl)

@@ -1,25 +1,19 @@
-"""The placement/exchange seam of the discrete-event engine.
+"""The placement seam of the discrete-event engine.
 
-``repro.core.events`` simulates rounds over *some* message pool; a
-``Placement`` decides where that pool (and the unit state it serves) lives
-and how messages move between its parts. The engine asks the placement
-four questions and nothing else:
+A ``Placement`` decides where the engine's message pool — and the unit
+state it serves — lives, and runs the simulation there. It answers two
+questions:
 
-- **pool allocation** — ``pool_capacity(cfg, ecfg)``: how many message
-  slots one pool holds (for a partitioned placement: per shard);
-- **round selection** — ``pack_scale`` / ``make_selector``: how the
-  minimal ``(time, generation, cascade-id)`` round key is found over a
-  pool (packed single-lane min when the key fits one uint32, exact
-  3-field lexicographic min otherwise);
-- **message routing** — ``routing(near)``: the static candidate tables
-  (source unit, destination unit, receiver-side direction code) for a
-  fire's outgoing weight broadcasts;
-- **execution** — ``build_runner(...)``: the compiled simulation loop
-  itself, ``go(state, samples, step_keys, lat_key) -> (state, aux,
-  report)``.
+- **pool capacity** — ``pool_capacity(cfg, ecfg)``: how many message slots
+  one pool holds (for a partitioned placement: per shard);
+- **execution** — ``build_runner(...)``: the compiled simulation loop,
+  ``go(state, samples, step_keys, lat_key) -> (state, aux, report)``.
 
-Placements are frozen dataclasses: hashable, so they key the engine's
-``lru_cache`` of jitted runners exactly like ``EventConfig`` does.
+What a pool does — round selection, the send side, the delivery round's
+receiver side — is one module below every placement (``repro.core.pool``);
+a placement's runner calls it and keeps what is its own. Placements are
+frozen dataclasses: hashable, so they key the engine's ``lru_cache`` of
+jitted runners exactly like ``EventConfig`` does.
 
 Two placements exist: ``SinglePool`` (one dense pool on one device — the
 historical engine, golden-suite-pinned bitwise) and ``MeshPlacement``
@@ -42,12 +36,6 @@ class Placement(Protocol):
     def shards(self) -> int: ...
 
     def pool_capacity(self, cfg, ecfg) -> int: ...
-
-    def pack_scale(self, cfg, ecfg, num_events: int) -> int | None: ...
-
-    def make_selector(self, cfg, ecfg, num_events: int): ...
-
-    def routing(self, near): ...
 
     def build_runner(self, cfg, ecfg, num_events: int,
                      search, p_fn, l_c_fn): ...

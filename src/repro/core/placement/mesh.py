@@ -16,19 +16,15 @@ Execution model (DESIGN.md §10):
   delivers it; shards working on different cascades in the same iteration
   is the intended semantics, not a race. The loop continues while any
   shard still has a due message (one scalar ``psum`` per iteration).
-- **delivery width** — a round's receiver side runs at the single pool's
-  narrow width (``core.events._NARROW_WIDTH``, via ``_compress``) when the
-  round selects at most that many messages, and at the worst-case width
-  otherwise; both widths add the same operands in the same order
-  (``EventReport.narrow_rounds`` counts the narrow ones, over shards).
-- **enqueue width** — ``fire()``'s enqueue on a band runs at the same
-  narrow width when at most ``_NARROW_WIDTH // 4`` of the band's units
-  fire, over all 4L candidates when more do, and not at all when none
-  does; a halo's arrivals enqueue at the narrow width when at most that
-  many are valid, and not at all when none is. Slots come from
-  ``core.events._allocate`` at either width, so the width never shows in
-  the pool (``EventReport.narrow_fires`` and ``wide_fires`` count the fire
-  enqueues, over shards).
+- **widths** — the band's pool is a ``core.pool`` pool, as the single
+  pool is: a round's receiver side runs at ``core.pool.NARROW_WIDTH`` when
+  the round selects at most that many messages and at the worst-case width
+  otherwise (``EventReport.narrow_rounds`` counts the narrow ones, over
+  shards); ``fire()``'s enqueue runs at that width when at most a quarter
+  as many of the band's units fire, over all 4L candidates when more do,
+  and not at all when none does (``narrow_fires`` and ``wide_fires``); a
+  halo's arrivals enqueue at that width when at most that many are valid,
+  and not at all when none is. The width never shows in the result.
 - **halo exchange** — a delivery round's refires (and each sample round's
   threshold crossing) return an *outbox*: boundary-row fire masks plus the
   boundary-row weights. The exchange itself runs unconditionally every
@@ -59,7 +55,6 @@ bitwise contract exact rather than merely close).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import threading
 from typing import NamedTuple
 
@@ -69,8 +64,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro import obs
 from repro.core import afm as afm_lib
+from repro.core import events as events_lib
+from repro.core import pool as pool_lib
 from repro.core.distributed import _argmin_over_axis
-from repro.core.placement import single as single_mod
+from repro.core.placement.single import SinglePool
 from repro.sharding import compat
 
 #: Mesh axis name the event engine shards over.
@@ -155,11 +152,9 @@ class _Carry(NamedTuple):
     halo_sent: jnp.ndarray      # () i32 halo candidates enqueued here
 
 
-#: The carry fields an enqueue writes (``fault_key``, which its loss draw
-#: advances, last).
-_POOL_FIELDS = ("msg_t", "msg_gen", "msg_cid", "msg_dst", "msg_dir", "msg_w",
-                "free_head", "free_n", "dropped", "sent", "dropped_fault",
-                "fault_key")
+#: The round key's lanes in a band's pool: halo metadata travels unpacked,
+#: so the bands select by the exact lexicographic min.
+_LANES = ("msg_gen", "msg_cid")
 
 
 class _Outbox(NamedTuple):
@@ -196,34 +191,14 @@ class MeshPlacement:
         """Per-shard pool slots: an even split of ``capacity``, or 8 · L.
         An active fault plan's ``pool_reserve`` withholds slots from every
         shard's pool (forced overflow pressure, counted as overflow)."""
-        n_local = max(1, cfg.n_units // self.shards)
-        m = (ecfg.capacity // self.shards if ecfg.capacity is not None
-             else 8 * n_local)
-        if ecfg.fault_active:
-            m = int(m) - ecfg.plan.pool_reserve
-        return max(int(m), 4)
-
-    def pack_scale(self, cfg, ecfg, num_events: int) -> None:
-        """Mesh pools always use the exact lexicographic selector (per-shard
-        gen/cid stay plain int32 lanes — halo metadata travels unpacked)."""
-        return None
-
-    def make_selector(self, cfg, ecfg, num_events: int):
-        def select(msg_t, msg_key, msg_gen, msg_cid):
-            del msg_key
-            return single_mod.pool_min_lex(msg_t, msg_gen, msg_cid)
-        return select
-
-    def routing(self, near):
-        """Global-lattice candidate tables (the mesh runner derives its
-        shard-local equivalents internally — see ``_build_mesh_runner``)."""
-        return single_mod.SinglePool().routing(near)
+        return pool_lib.capacity(ecfg, max(1, cfg.n_units // self.shards),
+                                 self.shards)
 
     def build_runner(self, cfg, ecfg, num_events: int, search, p_fn, l_c_fn):
         if self.shards == 1:
             # no partition boundary: the single-pool runner IS the 1-shard
             # mesh, making shards=1 ≡ SinglePool bitwise by construction
-            return single_mod.SinglePool().build_runner(
+            return SinglePool().build_runner(
                 cfg, ecfg, num_events, search, p_fn, l_c_fn)
         if cfg.side % self.shards:
             raise ValueError(
@@ -253,8 +228,6 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
     """Compile-time construction of the sharded runner ``go(state, samples,
     step_keys, lat_key)``. See the module docstring for the execution model;
     every closure below is per-shard code inside one ``shard_map``."""
-    from repro.core import events as events_lib
-
     k_shards = pl.shards
     side, d, theta = cfg.side, cfg.dim, cfg.theta
     n = cfg.n_units
@@ -263,18 +236,15 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
     e = num_events
     spacing = ecfg.sample_spacing
     m = pl.pool_capacity(cfg, ecfg)
-    # a round's selection width: one local fire (≤ 4L) plus one halo burst
-    # (≤ 2·side) at zero/constant latency; exponential ties can span the pool
-    k_round = m if ecfg.latency == "exponential" else min(4 * length
-                                                          + 2 * side, m)
-    # rounds that select at most k_narrow messages run at that width; so do
+    # a round's selection: one local fire (≤ 4L) plus one halo burst
+    # (≤ 2·side) at zero/constant latency
+    widths = pool_lib.round_widths(ecfg, m, 4 * length + 2 * side)
     # fires of at most fire_units units and halos of at most halo_width
-    # arrivals
-    k_narrow = min(k_round, events_lib._NARROW_WIDTH)
-    fire_units = events_lib._fire_units(length)
-    halo_width = (events_lib._NARROW_WIDTH
-                  if events_lib._NARROW_WIDTH < 2 * side else 0)
-    max_waves = single_mod.wave_cap(cfg)
+    # arrivals enqueue at the narrow width
+    fire_units = pool_lib.fire_units(length)
+    halo_width = (pool_lib.NARROW_WIDTH
+                  if pool_lib.NARROW_WIDTH < 2 * side else 0)
+    max_waves = events_lib.wave_cap(cfg)
     iter_cap = min(e * (max_waves + 2) + 1, 2 ** 31 - 1)
     e_local = max(1, cfg.e // k_shards)
     exact = search is afm_lib.search_exact
@@ -297,16 +267,14 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
     uu = jnp.arange(length, dtype=jnp.int32)
     rr, ss = uu // side, uu % side
     # candidate order (up, down, left, right) == receiver direction codes
-    # (0 from-below, 1 from-above, 2 from-right, 3 from-left) — the same
-    # slot convention as core.events / core.cascade._shift4
-    dst_local = jnp.stack([
+    # (0 from-below, 1 from-above, 2 from-right, 3 from-left), as in
+    # core.pool.routes
+    tables = pool_lib.routes(jnp.stack([
         jnp.where(rr > 0, uu - side, -1),
         jnp.where(rr < rows - 1, uu + side, -1),
         jnp.where(ss > 0, uu - 1, -1),
         jnp.where(ss < side - 1, uu + 1, -1),
-    ], axis=1).reshape(-1)                                       # (4L,)
-    dirs4 = jnp.tile(jnp.arange(4, dtype=jnp.int32), (length, 1)).reshape(-1)
-    src4 = jnp.repeat(uu, 4)
+    ], axis=1).reshape(-1))                                      # (4L,)
     # halo arrival tables: from-above lands on my row 0 (dir 1 = from
     # row-1), from-below lands on my last row (dir 0 = from row+1)
     halo_dst = jnp.concatenate([
@@ -317,29 +285,16 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
     dn_perm = [(i, (i + 1) % k_shards) for i in range(k_shards)]
     up_perm = [(i, (i - 1) % k_shards) for i in range(k_shards)]
 
-    def delays(lat_sub, count: int):
-        if ecfg.latency == "exponential":
-            base = jax.random.exponential(lat_sub, (count,)) * ecfg.delay
-        elif ecfg.latency == "constant":
-            base = jnp.full((count,), ecfg.delay, jnp.float32)
-        else:
-            base = jnp.zeros((count,), jnp.float32)
-        if straggle_on:
-            # straggler injection: everything entering shard k's pool takes
-            # mult[k]x longer (a slow host delays the messages it owns —
-            # halo arrivals draw delays receiver-side, so this covers
-            # cross-shard traffic into the straggler too)
-            mults = jnp.asarray(plan.shard_latency_mult, jnp.float32)
-            base = base * mults[jax.lax.axis_index(AXIS)]
-        return base
-
-    def split_lat(lat_key):
-        # the stream advances once per draw site whether or not anything
-        # fired — zero/constant draws consume no bits (same discipline as
-        # the single-pool engine)
-        if ecfg.latency == "exponential":
-            return jax.random.split(lat_key)
-        return lat_key, lat_key
+    def straggle():
+        """This shard's latency factor under a straggler fault, else None:
+        everything entering shard k's pool takes mult[k]x longer (a slow
+        host delays the messages it owns — halo arrivals draw delays
+        receiver-side, so this covers cross-shard traffic into the
+        straggler too)."""
+        if not straggle_on:
+            return None
+        mults = jnp.asarray(plan.shard_latency_mult, jnp.float32)
+        return mults[jax.lax.axis_index(AXIS)]
 
     def empty_outbox():
         zi = jnp.zeros((side,), jnp.int32)
@@ -347,94 +302,25 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         return _Outbox(zi, zw, zi, zw, jnp.zeros((1,), jnp.float32),
                        jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32))
 
-    def lose(cy: _Carry, count: int):
-        """The loss draw over a site's ``count`` candidates, at the site's
-        full width whatever width its enqueue runs at: (carry, keep), with
-        ``keep`` None and the fault stream untouched without ``p_loss``."""
-        if not loss_on:
-            return cy, None
-        fkey, sub = jax.random.split(cy.fault_key)
-        return (cy._replace(fault_key=fkey),
-                jax.random.uniform(sub, (count,)) >= plan.p_loss)
-
-    def enqueue(cy: _Carry, valid, keep, dstv, dirv, wv, tv, genv,
-                cidv) -> _Carry:
-        """Allocate pool slots off the free ring for the valid candidates
-        (``events._allocate``): the r-th valid candidate takes the r-th
-        free slot; candidates past the free count are dropped and counted.
-        Fault accounting is owner-side: ``sent`` counts every valid
-        candidate before the loss draw ``keep``, so sent == delivered +
-        overflow + fault + stranded per shard."""
-        cy = cy._replace(sent=cy.sent + jnp.sum(valid, dtype=jnp.int32))
-        if keep is not None:
-            cy = cy._replace(dropped_fault=cy.dropped_fault
-                             + jnp.sum(valid & ~keep, dtype=jnp.int32))
-            valid = valid & keep
-        slot, nalloc, drop = events_lib._allocate(valid, cy.free_ring,
-                                                  cy.free_head, cy.free_n)
-        return cy._replace(
-            msg_t=cy.msg_t.at[slot].set(tv, mode="drop"),
-            msg_gen=cy.msg_gen.at[slot].set(genv, mode="drop"),
-            msg_cid=cy.msg_cid.at[slot].set(cidv, mode="drop"),
-            msg_dst=cy.msg_dst.at[slot].set(dstv, mode="drop"),
-            msg_dir=cy.msg_dir.at[slot].set(dirv, mode="drop"),
-            msg_w=cy.msg_w.at[slot].set(wv, mode="drop"),
-            free_head=(cy.free_head + nalloc) % m,
-            free_n=cy.free_n - nalloc,
-            dropped=cy.dropped + drop)
-
-    def pool_of(cy: _Carry):
-        return tuple(getattr(cy, f) for f in _POOL_FIELDS)
-
-    def with_pool(cy: _Carry, pool) -> _Carry:
-        return cy._replace(**dict(zip(_POOL_FIELDS, pool)))
+    def skip(pool):
+        # a fire of no unit: only the loss stream moves, as it did in the
+        # unconditional enqueue this skip replaced
+        if loss_on:
+            pool = dict(pool, fault_key=jax.random.split(pool["fault_key"])[0])
+        return pool
 
     def fire(cy: _Carry, me, fired, cid, t, gen):
-        """Broadcast-after-theta on the local band: reset counters, enqueue
-        the in-shard neighbour messages, and emit the boundary-row firings
-        as this round's outbox (delivered by the caller's exchange). As on
-        the single pool, the enqueue runs at the narrow width when at most
-        ``fire_units`` units fire, over all 4L candidates when more do, and
-        not at all when none does."""
-        nfired = jnp.sum(fired, dtype=jnp.int32)
-        cy = cy._replace(sizes=cy.sizes.at[cid].add(nfired),
-                         c=jnp.where(fired, 0, cy.c))
-        lat_key, lat_sub = split_lat(cy.lat_key)
-        cy = cy._replace(lat_key=lat_key)
+        """Broadcast-after-theta on the local band (``core.pool.fire``):
+        reset counters, enqueue the in-shard neighbour messages, and emit
+        the boundary-row firings as this round's outbox (delivered by the
+        caller's exchange). Fault accounting is owner-side: ``sent`` counts
+        every valid candidate before the loss draw, so sent == delivered +
+        overflow + fault + stranded per shard."""
         gi = jnp.asarray(gen, jnp.int32)
         ci = jnp.asarray(cid, jnp.int32)
-
-        # the branches close over exactly the pool fields enqueue mutates
-        # (as the single pool's fire does), so the skip branch is a no-op
-        # over small operands
-        def send(narrow: bool, pool):
-            cz, keep = lose(with_pool(cy, pool), 4 * length)
-            tv = t + delays(lat_sub, 4 * length)
-            if narrow:
-                ids, real = events_lib._fire_candidates(fired, fire_units)
-                dst = dst_local[ids]
-                cz = enqueue(cz, real & (dst >= 0),
-                             None if keep is None else keep[ids], dst,
-                             dirs4[ids], cy.w[src4[ids]], tv[ids], gi, ci)
-            else:
-                cz = enqueue(cz, fired[src4] & (dst_local >= 0), keep,
-                             dst_local, dirs4, cy.w[src4], tv, gi, ci)
-            return pool_of(cz)
-
-        def skip(pool):
-            # enqueue with no valid candidate: only the loss stream moves
-            if loss_on:
-                pool = (*pool[:-1], jax.random.split(pool[-1])[0])
-            return pool
-
-        # most rounds fire nothing on most bands: skip the enqueue
-        with jax.named_scope(obs.EVENTS_POOL):
-            pool, narrow = events_lib._enqueue_by_count(
-                nfired, fire_units, skip, send, pool_of(cy))
-        cy = with_pool(cy, pool)._replace(
-            narrow_fires=cy.narrow_fires + narrow.astype(jnp.int32),
-            wide_fires=cy.wide_fires
-            + ((nfired > 0) & ~narrow).astype(jnp.int32))
+        cy = pool_lib.fire(cy, fired, cid, t, {"msg_gen": gi, "msg_cid": ci},
+                           tables, fire_units, ecfg, skip=skip,
+                           mult=straggle())
         out = _Outbox(
             up_mask=(fired[:side] & (me > 0)).astype(jnp.int32),
             up_w=cy.w[:side],
@@ -470,42 +356,41 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
         # senders zero their boundary masks at the lattice edge, so the
         # ring wrap (shard K-1 -> 0 and 0 -> K-1) arrives all-invalid
         valid = jnp.concatenate([a_mask, b_mask]) != 0
-        lat_key, lat_sub = split_lat(cy.lat_key)
-        cy = cy._replace(lat_key=lat_key)
+        lat_key, lat_sub = pool_lib.split_latency(ecfg, cy.lat_key)
         tv = jnp.concatenate([jnp.full((side,), a_t[0]),
                               jnp.full((side,), b_t[0])])
-        tv = tv + delays(lat_sub, 2 * side)
+        tv = tv + pool_lib.delays(ecfg, lat_sub, 2 * side, straggle())
         genv = jnp.concatenate([jnp.full((side,), a_gen[0]),
                                 jnp.full((side,), b_gen[0])])
         cidv = jnp.concatenate([jnp.full((side,), a_cid[0]),
                                 jnp.full((side,), b_cid[0])])
         wv = jnp.concatenate([a_w, b_w], axis=0)
         nvalid = jnp.sum(valid, dtype=jnp.int32)
-        cy = cy._replace(exchanges=cy.exchanges + 1,
+        fault_key, keep = pool_lib.lose(ecfg, cy.fault_key, 2 * side)
+        cy = cy._replace(lat_key=lat_key, fault_key=fault_key,
+                         exchanges=cy.exchanges + 1,
                          halo_sent=cy.halo_sent + nvalid)
-        cy, keep = lose(cy, 2 * side)
 
         def arrive(narrow: bool, pool):
-            cz = with_pool(cy, pool)
             if narrow:
                 # the valid arrivals in ascending order, as the wide
                 # enqueue ranks them
-                idx = events_lib._compress(valid, halo_width)
+                idx = pool_lib.compress(valid, halo_width)
                 ii = jnp.minimum(idx, 2 * side - 1)
-                cz = enqueue(cz, idx < 2 * side,
-                             None if keep is None else keep[ii],
-                             halo_dst[ii], halo_dir[ii], wv[ii], tv[ii],
-                             genv[ii], cidv[ii])
-            else:
-                cz = enqueue(cz, valid, keep, halo_dst, halo_dir, wv, tv,
-                             genv, cidv)
-            return pool_of(cz)
+                return pool_lib.enqueue(
+                    pool, cy.free_ring, idx < 2 * side,
+                    None if keep is None else keep[ii], halo_dst[ii],
+                    halo_dir[ii], wv[ii], tv[ii],
+                    {"msg_gen": genv[ii], "msg_cid": cidv[ii]})
+            return pool_lib.enqueue(pool, cy.free_ring, valid, keep,
+                                    halo_dst, halo_dir, wv, tv,
+                                    {"msg_gen": genv, "msg_cid": cidv})
 
         # most iterations carry no arrival, and a burst seldom more than
         # halo_width: skip the enqueue, or run it at that width
-        pool, _ = events_lib._enqueue_by_count(
-            nvalid, halo_width, lambda p: p, arrive, pool_of(cy))
-        return with_pool(cy, pool)
+        pool, _ = pool_lib.enqueue_by_count(
+            nvalid, halo_width, lambda p: p, arrive, pool_lib.view(cy, _LANES))
+        return cy._replace(**pool)
 
     def make_round_fns(me, i0, near_g, far_g):
         """Per-shard round handlers (closures over the shard index, the
@@ -523,104 +408,25 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
                 return dead_band & (t >= d_lo) & (t < d_hi)
 
         def delivery_round(cy: _Carry, tmin, gmin, cmin, sel):
-            """Deliver one local round: the selected slots are compressed
-            out of the pool, segment-summed per receiver in direction-slot
-            order, and applied as a row scatter — the single-pool delivery
-            math on the local band. As in the single pool, the receiver side
-            runs at ``k_narrow`` when the round selects at most that many
-            messages and at ``k_round`` otherwise; both widths add the same
-            operands in the same order, so the choice never shows in the
-            result. Refire gating uses the message generation (``gmin <
-            max_waves``), which is the globally consistent wave depth
-            regardless of how many rounds this shard happened to process."""
+            """Deliver one local round (``core.pool.deliver``, the single
+            pool's delivery on the local band). Refire gating uses the
+            message generation (``gmin < max_waves``), which is the globally
+            consistent wave depth regardless of how many rounds this shard
+            happened to process."""
             cid = cmin
             sched_i = i0 + cid
-            l_c = l_c_fn(sched_i, cfg)
-            p_i = p_fn(sched_i, cfg)
-            ck, sub = jax.random.split(cy.casc_key[cid])
-            bern = (jax.random.uniform(sub, (4, rows, side))
-                    < p_i).reshape(4, length)
-            nsel = jnp.sum(sel, dtype=jnp.int32)
-
-            def receive(width, _=None):
-                """The receiver side at static ``width`` ≥ ``nsel``: returns
-                (w, c, n_recv, ndeliv, free_ring)."""
-                with jax.named_scope(obs.EVENTS_POOL):
-                    idx = events_lib._compress(sel, width)
-                    taken = idx < m
-                    ii = jnp.minimum(idx, m - 1)
-                    dsts = jnp.where(taken, cy.msg_dst[ii], length)
-                    dirs = jnp.where(taken, cy.msg_dir[ii], 0)
-                    ws = cy.msg_w[ii]
-                with jax.named_scope(obs.EVENTS_DELIVER):
-                    ok = taken
-                    if dead_on:
-                        # messages to a dead local unit are consumed but not
-                        # delivered (dropped_fault); their slots free
-                        # normally
-                        ok = ok & ~dead_at(tmin)[jnp.minimum(dsts,
-                                                             length - 1)]
-                    drive = jnp.where(
-                        ok, bern[dirs, jnp.minimum(dsts, length - 1)], False)
-                    c = cy.c.at[dsts].add(drive.astype(jnp.int32),
-                                          mode="drop")
-                    n_recv = jnp.zeros((length,), jnp.int32).at[dsts].add(
-                        ok.astype(jnp.int32), mode="drop")
-                    ridx = events_lib._compress(n_recv > 0, width)
-                    pos = jnp.searchsorted(ridx, dsts)
-                    acc = jnp.zeros((width, d), jnp.float32)
-                    for s4 in range(4):              # direction-slot order
-                        acc = acc.at[jnp.where(ok & (dirs == s4), pos,
-                                               width)].add(ws, mode="drop")
-                    rv = jnp.minimum(ridx, length - 1)
-                    nf = n_recv[rv].astype(cy.w.dtype)
-                    wr = cy.w[rv]
-                    w_rows = wr + l_c * (acc - nf[:, None] * wr)
-                    w = cy.w.at[ridx].set(w_rows, mode="drop")
-                ndeliv = jnp.sum(ok, dtype=jnp.int32) if dead_on else nsel
-                # free the taken slots: push their ids onto the ring tail,
-                # in ascending slot order
-                with jax.named_scope(obs.EVENTS_POOL):
-                    tail = jnp.where(
-                        taken,
-                        (cy.free_head + cy.free_n
-                         + jnp.arange(width, dtype=jnp.int32)) % m, m)
-                    free_ring = cy.free_ring.at[tail].set(idx, mode="drop")
-                return w, c, n_recv, ndeliv, free_ring
-
-            if k_narrow < k_round:
-                narrow = nsel <= k_narrow
-                w, c, n_recv, ndeliv, free_ring = jax.lax.cond(
-                    narrow, functools.partial(receive, k_narrow),
-                    functools.partial(receive, k_round), None)
-                narrow_rounds = cy.narrow_rounds + narrow.astype(jnp.int32)
-            else:
-                w, c, n_recv, ndeliv, free_ring = receive(k_round)
-                narrow_rounds = cy.narrow_rounds
-            received = n_recv > 0
-            extra = {}
-            if dead_on:
-                extra["dropped_fault"] = cy.dropped_fault + (nsel - ndeliv)
-            with jax.named_scope(obs.EVENTS_POOL):
-                msg_t = jnp.where(sel, jnp.inf, cy.msg_t)
+            dead = dead_at(tmin) if dead_on else None
+            cy, received = pool_lib.deliver(
+                cy, tmin, cid, sel, (rows, side), p_fn(sched_i, cfg),
+                l_c_fn(sched_i, cfg), widths, dead)
             cy = cy._replace(
-                w=w, c=c, t=jnp.maximum(cy.t, tmin),
-                clock=jnp.where(received, tmin, cy.clock),
-                nevents=cy.nevents + n_recv,
-                msg_t=msg_t,
-                free_ring=free_ring,
-                free_n=cy.free_n + nsel,
-                casc_key=cy.casc_key.at[cid].set(ck),
+                t=jnp.maximum(cy.t, tmin),
                 wcount=cy.wcount.at[cid].set(
                     jnp.maximum(cy.wcount[cid], gmin)),
-                deliveries=cy.deliveries + ndeliv,
-                drounds=cy.drounds + 1,
-                narrow_rounds=narrow_rounds,
-                **extra)
-            new_fired = (c >= theta) & received
-            allowed = new_fired & (gmin < max_waves)
+                drounds=cy.drounds + 1)
+            allowed = (cy.c >= theta) & received & (gmin < max_waves)
             if dead_on:
-                allowed = allowed & ~dead_at(tmin)
+                allowed = allowed & ~dead
             return fire(cy, me, allowed, cid, tmin, gmin + 1)
 
         def greedy(w_loc, sample, jstar, qstar):
@@ -736,8 +542,8 @@ def _build_mesh_runner(pl: MeshPlacement, cfg, ecfg, num_events: int,
             halos unconditionally and re-select."""
             def select(cy):
                 with jax.named_scope(obs.EVENTS_POOL):
-                    return single_mod.pool_min_lex(cy.msg_t, cy.msg_gen,
-                                                   cy.msg_cid)
+                    return pool_lib.pool_min_lex(cy.msg_t, cy.msg_gen,
+                                                 cy.msg_cid)
 
             def dcond(st):
                 cy_, (tmin, _g, _c, _s, have), it = st

@@ -1,94 +1,19 @@
 """``SinglePool`` — one dense message pool on one device.
 
-The historical engine layout, extracted behind the placement seam
-(``repro.core.placement.base``) without changing a single op: the round
-selectors (packed / lexicographic pool-min), the pool-capacity rule, and
-the fire-candidate routing tables live here, and ``build_runner`` dispatches
-to the engine's three runners (fused zero-latency scan / sample-scan engine
-/ budgeted loop) exactly as ``core.events`` always has. The golden
-fingerprint suite (``tests/golden/async_engine.npz``) pins this placement
-bitwise across all three latency models.
+The historical engine layout behind the placement seam
+(``repro.core.placement.base``): its pool holds 8·N slots by default
+(``core.pool.capacity``), and ``build_runner`` dispatches to the engine's
+three runners in ``core.events`` (fused zero-latency scan / sample-scan
+engine / budgeted loop). The golden fingerprint suite
+(``tests/golden/async_engine.npz``) pins this placement bitwise across all
+three latency models.
 """
 from __future__ import annotations
 
 import dataclasses
 
-import jax
-import jax.numpy as jnp
-
-#: Bit pattern of float32 +inf. ``msg_t`` is always ≥ 0 (sample times and
-#: delays are non-negative), so bit-casting it to uint32 is order-preserving
-#: and a free slot (t = +inf) carries the largest key — the round-selection
-#: min needs no separate ``isfinite`` mask.
-INF_BITS = 0x7F800000
-
-
-def wave_cap(cfg) -> int:
-    """The engine's effective cascade wave bound (``None`` -> 8·side²)."""
-    return 8 * cfg.side * cfg.side if cfg.max_waves is None else cfg.max_waves
-
-
-def pool_capacity(cfg, ecfg) -> int:
-    """Pool slots for one dense pool: ``capacity`` or 8·N, at least 4.
-
-    An active fault plan's ``pool_reserve`` withholds slots (forced
-    overflow pressure — the drops land in ``dropped_overflow``, never the
-    fault counter, pinning the accounting split)."""
-    m = ecfg.capacity if ecfg.capacity is not None else 8 * cfg.n_units
-    if ecfg.fault_active:
-        m = int(m) - ecfg.plan.pool_reserve
-    return max(int(m), 4)
-
-
-def key_scale(num_events: int, max_waves: int) -> int | None:
-    """E if ``(gen, cid)`` packs losslessly into one uint32 lane (the common
-    case: key = gen · E + cid with gen ≤ max_waves + 1 and cid < E), else
-    ``None`` — the engine then falls back to the exact 3-field lexicographic
-    min, which is correct for any int32 gen/cid (no magic sentinel)."""
-    if num_events <= 0:
-        return None
-    if (max_waves + 2) * num_events <= 2 ** 32:
-        return num_events
-    return None
-
-
-def pool_min_lex(msg_t, msg_gen, msg_cid):
-    """Exact lexicographic min over active messages: (t, gen, cid) -> round.
-
-    The time lane is compared through its uint32 bit pattern (valid because
-    ``msg_t`` ≥ 0 and free slots are +inf — see ``INF_BITS``); gen/cid use
-    ``iinfo(int32).max`` as the masked fill, which stays correct even when a
-    real gen/cid equals the fill (the old engine's ``2**30`` sentinel broke
-    there — see the regression test)."""
-    hi = jax.lax.bitcast_convert_type(msg_t, jnp.uint32)
-    hi_min = jnp.min(hi)
-    have = hi_min != jnp.uint32(INF_BITS)
-    imax = jnp.int32(jnp.iinfo(jnp.int32).max)
-    m1 = hi == hi_min
-    gmin = jnp.min(jnp.where(m1, msg_gen, imax))
-    m2 = m1 & (msg_gen == gmin)
-    cmin = jnp.min(jnp.where(m2, msg_cid, imax))
-    sel = m2 & (msg_cid == cmin)
-    tmin = jax.lax.bitcast_convert_type(hi_min, jnp.float32)
-    return tmin, gmin, cmin, sel, have
-
-
-def pool_min_packed(msg_t, msg_key, scale: int):
-    """Packed round-key min: 2 reduction passes instead of 3.
-
-    Lane 1 is the bit-cast time, lane 2 the packed ``gen · scale + cid``
-    (``scale`` == E, statically guaranteed not to overflow uint32 by
-    ``key_scale``)."""
-    hi = jax.lax.bitcast_convert_type(msg_t, jnp.uint32)
-    hi_min = jnp.min(hi)
-    have = hi_min != jnp.uint32(INF_BITS)
-    lo_min = jnp.min(jnp.where(hi == hi_min, msg_key,
-                               jnp.uint32(0xFFFFFFFF)))
-    sel = (hi == hi_min) & (msg_key == lo_min)
-    tmin = jax.lax.bitcast_convert_type(hi_min, jnp.float32)
-    gmin = (lo_min // jnp.uint32(scale)).astype(jnp.int32)
-    cmin = (lo_min % jnp.uint32(scale)).astype(jnp.int32)
-    return tmin, gmin, cmin, sel, have
+from repro.core import events
+from repro.core import pool as pool_lib
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,44 +31,12 @@ class SinglePool:
         return 1
 
     def pool_capacity(self, cfg, ecfg) -> int:
-        return pool_capacity(cfg, ecfg)
-
-    def pack_scale(self, cfg, ecfg, num_events: int) -> int | None:
-        return key_scale(num_events, wave_cap(cfg))
-
-    def make_selector(self, cfg, ecfg, num_events: int):
-        """Round selector over the pool's key lanes. The packed single-lane
-        min applies whenever ``(gen, cid)`` fits one uint32 (``pack_scale``);
-        otherwise the exact lexicographic 3-field min."""
-        scale = self.pack_scale(cfg, ecfg, num_events)
-        if scale is not None:
-            def select(msg_t, msg_key, msg_gen, msg_cid):
-                del msg_gen, msg_cid
-                return pool_min_packed(msg_t, msg_key, scale)
-        else:
-            def select(msg_t, msg_key, msg_gen, msg_cid):
-                del msg_key
-                return pool_min_lex(msg_t, msg_gen, msg_cid)
-        return select
-
-    def routing(self, near):
-        """Static fire-candidate tables over the full lattice: the r-th
-        unit's 4 outgoing messages in ``near``-table order (up, down, left,
-        right), which land on the receiver direction codes (from-below,
-        from-above, from-right, from-left) in that same slot order."""
-        n = near.shape[0]
-        dirs4 = jnp.tile(jnp.arange(4, dtype=jnp.int32), (n, 1)).reshape(-1)
-        src4 = jnp.repeat(jnp.arange(n, dtype=jnp.int32), 4)
-        dst4 = near.reshape(-1)
-        return src4, dst4, dirs4
+        return pool_lib.capacity(ecfg, cfg.n_units)
 
     def build_runner(self, cfg, ecfg, num_events: int, search, p_fn, l_c_fn):
         """Statically dispatch to the engine's three runners — fused
         zero-latency scan, sample-scan engine, or budgeted loop — exactly
         as the pre-seam engine did (DESIGN.md §7)."""
-        # late import: events imports this module for its selector aliases
-        from repro.core import events
-
         if ecfg.fault_active and ecfg.plan.shard_latency_mult:
             raise ValueError(
                 "FaultPlan.shard_latency_mult injects per-shard stragglers "
@@ -161,6 +54,6 @@ class SinglePool:
                 "fit the pool); raise capacity or drop the kernel override")
         if ecfg.max_rounds is None:
             return events._make_engine(cfg, ecfg, num_events,
-                                       search, p_fn, l_c_fn, placement=self)
+                                       search, p_fn, l_c_fn)
         return events._make_budgeted(cfg, ecfg, num_events,
-                                     search, p_fn, l_c_fn, placement=self)
+                                     search, p_fn, l_c_fn)

@@ -33,9 +33,8 @@ import pytest
 
 from repro.analysis.runtime import TraceGuard
 from repro.api import AFMConfig, TopoMap, available_backends, get_backend
-from repro.core import afm, events, sandpile
+from repro.core import afm, events, pool, sandpile
 from repro.core import search as search_lib
-from repro.core.placement import SinglePool
 from repro.data import make_dataset
 from repro.faults import FaultPlan
 from repro.launch.stream_train import run_stream
@@ -432,7 +431,7 @@ def narrow_width(monkeypatch):
     dropped on each change and after the test, so none built at a patched
     width outlives it."""
     def set_width(width):
-        monkeypatch.setattr(events, "_NARROW_WIDTH", width)
+        monkeypatch.setattr(pool, "NARROW_WIDTH", width)
         events._compiled_runner.cache_clear()
     yield set_width
     events._compiled_runner.cache_clear()
@@ -475,9 +474,9 @@ def test_narrow_and_wide_delivery_rounds_agree_bitwise(case, narrow_width,
         assert int(rep_narrow.dropped_fault) == int(rep_wide.dropped_fault) > 0
 
 
-@pytest.mark.parametrize("width", [1, 4, events._NARROW_WIDTH])
+@pytest.mark.parametrize("width", [1, 4, pool.NARROW_WIDTH])
 def test_compress_matches_nonzero(width):
-    """At the narrow widths ``_compress`` counts ranks in place of calling
+    """At the narrow widths ``compress`` counts ranks in place of calling
     ``jnp.nonzero``, and equals it (the mask's size as fill) with fewer, as
     many and more True entries than ``width``."""
     rng = np.random.default_rng(width)
@@ -485,8 +484,8 @@ def test_compress_matches_nonzero(width):
         mask = np.zeros(size, bool)
         mask[rng.choice(size, min(ntrue, size), replace=False)] = True
         want = jnp.nonzero(mask, size=width, fill_value=size)[0]
-        np.testing.assert_array_equal(events._compress(jnp.asarray(mask),
-                                                       width), want)
+        np.testing.assert_array_equal(pool.compress(jnp.asarray(mask), width),
+                                      want)
 
 
 def test_narrow_rounds_count_the_delivery_rounds():
@@ -561,7 +560,7 @@ def test_narrow_and_wide_fires_agree_bitwise(case, narrow_width):
     cfg, ekw = _FIRE_CASES[case]
     ecfg = events.EventConfig(**ekw)
     n, e = cfg.n_units, 8
-    assert (SinglePool().pack_scale(cfg, ecfg, e) is None) == (case == "lex")
+    assert (pool.key_scale(e, events.wave_cap(cfg)) is None) == (case == "lex")
     state = afm.init(jax.random.PRNGKey(0), cfg)
     es0 = events.init_events(state, cfg, ecfg, e, jax.random.PRNGKey(1))
     m = es0.msg_t.shape[0]
@@ -573,7 +572,7 @@ def test_narrow_and_wide_fires_agree_bitwise(case, narrow_width):
         mask[rng.choice(n, k, replace=False)] = True
         masks.append(jnp.asarray(mask))
     outs = {}
-    for units in (events._NARROW_WIDTH // 4, 40, n):
+    for units in (pool.NARROW_WIDTH // 4, 40, n):
         narrow_width(4 * units)
         fire = jax.jit(events._make_round_fns(
             cfg, ecfg, e, afm.search_exact, events._default_p,
@@ -615,7 +614,7 @@ def test_fire_of_more_than_the_narrow_units_agrees_bitwise(narrow_width):
     keys = jax.random.split(jax.random.PRNGKey(0), 2)
     ecfg = events.EventConfig(latency="constant", delay=1.0)
     runs = []
-    for width in (events._NARROW_WIDTH, 4 * cfg.n_units):
+    for width in (pool.NARROW_WIDTH, 4 * cfg.n_units):
         narrow_width(width)
         runs.append(events.run_events(
             state, target, keys, cfg, ecfg, search=_site_search,
@@ -742,7 +741,7 @@ def test_pool_min_lex_survives_generations_near_int32_max():
     gen = jnp.asarray([2 ** 30 + 5, 2 ** 30 + 3, 0, 2 ** 30 + 3, 1],
                       jnp.int32)
     cid = jnp.asarray([7, 9, 0, 3, 0], jnp.int32)
-    tmin, gmin, cmin, sel, have = events._pool_min_lex(t, gen, cid)
+    tmin, gmin, cmin, sel, have = pool.pool_min_lex(t, gen, cid)
     assert bool(have) and float(tmin) == 1.0
     assert int(gmin) == 2 ** 30 + 3 and int(cmin) == 3
     assert list(np.asarray(sel)) == [False, False, False, True, False]
@@ -750,11 +749,11 @@ def test_pool_min_lex_survives_generations_near_int32_max():
     t2 = jnp.asarray([3.0, 3.0], jnp.float32)
     g2 = jnp.asarray([imax, imax], jnp.int32)
     c2 = jnp.asarray([5, 2], jnp.int32)
-    _, gmin2, cmin2, sel2, have2 = events._pool_min_lex(t2, g2, c2)
+    _, gmin2, cmin2, sel2, have2 = pool.pool_min_lex(t2, g2, c2)
     assert bool(have2) and int(gmin2) == imax and int(cmin2) == 2
     assert list(np.asarray(sel2)) == [False, True]
     # empty pool: have must be False
-    assert not bool(events._pool_min_lex(
+    assert not bool(pool.pool_min_lex(
         jnp.full((3,), inf), jnp.zeros(3, jnp.int32),
         jnp.zeros(3, jnp.int32))[-1])
 
@@ -766,8 +765,8 @@ def test_packed_key_and_lex_fallback_agree_bitwise():
     num_events = 48
     packed_cfg = dataclasses.replace(CFG, max_waves=288)
     lex_cfg = dataclasses.replace(CFG, max_waves=2 ** 27)
-    assert events._key_scale(num_events, 288) == num_events
-    assert events._key_scale(num_events, 2 ** 27) is None
+    assert pool.key_scale(num_events, 288) == num_events
+    assert pool.key_scale(num_events, 2 ** 27) is None
     x = _tiny_data()
     keys = jax.random.split(jax.random.PRNGKey(5), num_events)
     state = afm.init(jax.random.PRNGKey(1), CFG, x)

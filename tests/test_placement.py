@@ -128,6 +128,20 @@ def test_resolve_placement():
         MeshPlacement(shards=0)
 
 
+def test_pool_sits_below_the_engine_and_the_placements():
+    """``core.pool`` is the layer both placements call: importing it in a
+    fresh interpreter loads neither ``core.events`` nor ``core.placement``,
+    so no import runs back up from it."""
+    code = ("import sys, repro.core.pool; print(sorted(m for m in sys.modules"
+            " if m.startswith(('repro.core.events',"
+            " 'repro.core.placement'))))")
+    env = dict(os.environ, PYTHONPATH=os.path.join(_HERE, "..", "src"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "[]"
+
+
 def test_mesh_build_validation():
     cfg = AFMConfig(side=6, dim=4, i_max=16, e_factor=0.5)
     with pytest.raises(ValueError, match="divide"):
@@ -331,7 +345,7 @@ import jax.numpy as jnp
 import numpy as np
 from repro import obs
 from repro.api import AFMConfig
-from repro.core import afm, events
+from repro.core import afm, events, pool
 from repro.core.placement import MeshPlacement
 sys.path.insert(0, os.environ["MESH_REF_DIR"])
 import mesh_ref
@@ -367,7 +381,7 @@ def flat(out):
                           narrow_rounds=0, narrow_fires=0, wide_fires=0))]
 
 def set_width(width):
-    events._NARROW_WIDTH = width
+    pool.NARROW_WIDTH = width
     events._compiled_runner.cache_clear()
 
 def fires(out):
@@ -392,7 +406,7 @@ def cascade():
         placement="mesh", shards=2)
     return [(x, steps, k_lat, st, aux, rep)]
 
-default_width = events._NARROW_WIDTH
+default_width = pool.NARROW_WIDTH
 narrow = runs()
 again = runs()
 fn = events._compiled_runner(cfg, ecfg, 4, afm.search_exact,
